@@ -51,10 +51,9 @@ fleet of 2 mesh-sharded schedulers (user-routed; KV page pool sharded over
 the ``data`` axis, KV heads over ``model`` — docs/sharding.md) with the
 per-shard ``serve.*`` registries merged into one fleet telemetry snapshot.
 The run exits nonzero if the fleet's scores drift more than 1e-4 from the
-unsharded scheduler drain. On fewer than DP*MP devices the block is still
-emitted on a degenerate (1, 1) mesh (``mesh_fallback: true``); the
-``tier1-multidevice`` CI lane forces 8 host devices for the real (2, 4)
-placement.
+unsharded scheduler drain. It raises when the runtime has fewer than
+DP*MP devices; the ``tier1-multidevice`` CI lane forces 8 host devices
+for the (2, 4) placement.
 
 ``--trace PATH`` exports the scheduler mode's final drain as a
 Chrome-trace-event JSON (``repro.obs.trace``): nested scheduler-step ->
@@ -101,7 +100,8 @@ from repro.configs import get_arch
 from repro.core.dti import build_sliding_prompts
 from repro.data.requests import make_request_stream
 from repro.data.synthetic import make_ctr_dataset
-from repro.launch.mesh import make_cpu_mesh, make_serve_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_serve_mesh
 from repro.models.transformer import init_params
 from repro.obs import profile as obs_profile
 from repro.obs.trace import SpanTracer, validate_chrome_trace
@@ -272,19 +272,12 @@ def run_sharded_fleet(params, cfg, requests, *, n_slots, capacity, buckets,
     """The scale-out drain (docs/sharding.md): a fleet of mesh-sharded
     schedulers splitting the request stream by user, each with its KV page
     pool sharded over ``data`` and KV heads over ``model``
-    (``ServeScheduler(mesh=...)``). Falls back to the degenerate (1, 1)
-    mesh when the runtime has fewer than ``dp * mp`` devices — the
-    single-device CI job still emits the block, the forced-8-device lane
-    exercises the real (2, 4) placement. Scores come back in submission
+    (``ServeScheduler(mesh=...)``). ``make_serve_mesh`` raises when the
+    runtime has fewer than ``dp * mp`` devices. Scores come back in submission
     order so the caller can diff them against the unsharded drain;
     telemetry is the per-shard ``serve.*`` registries merged into one
     fleet snapshot (``fleet_serve_snapshot``)."""
-    try:
-        mesh = make_serve_mesh(dp, mp)
-        fallback = False
-    except ValueError:
-        mesh = make_cpu_mesh()
-        fallback = True
+    mesh = make_serve_mesh(dp, mp)
     scheds = [ServeScheduler(params, cfg, n_slots=n_slots,
                              capacity=capacity, window=cfg.window,
                              buckets=buckets, mesh=mesh)
@@ -312,7 +305,6 @@ def run_sharded_fleet(params, cfg, requests, *, n_slots, capacity, buckets,
                    hit_fraction=hits / max(logical, 1))
     out["requested_mesh"] = [dp, mp]
     out["mesh"] = {str(a): int(n) for a, n in mesh.shape.items()}
-    out["mesh_fallback"] = fallback
     out["devices"] = len(jax.devices())
     out["fleet"] = fleet
     out["requests_per_shard"] = [len(p) for p in parts]
@@ -360,6 +352,7 @@ def run_quant_compare(params, cfg, requests, *, n_slots, capacity, buckets,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (small stream, same code path)")
@@ -432,10 +425,9 @@ def main():
                          "over 'model') on a (DP, MP) device mesh; emits a "
                          "'sharded' block with per-shard-merged serve.* "
                          "telemetry and the max |score delta| vs the "
-                         "unsharded scheduler drain. Falls back to a (1,1) "
-                         "mesh when the runtime has < DP*MP devices (set "
-                         "XLA_FLAGS=--xla_force_host_platform_device_count"
-                         "=8 for the real placement)")
+                         "unsharded scheduler drain. Raises when the "
+                         "runtime has < DP*MP devices (set XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=8 on CPU)")
     ap.add_argument("--jax-profile", default=None, dest="jax_profile",
                     metavar="DIR",
                     help="also capture a jax.profiler device trace of the "
@@ -639,11 +631,7 @@ def main():
         result["sharded"] = sharded
         mt = sharded["merged_telemetry"]
         print(f"  sharded mesh={sharded['mesh']}"
-              + (" (FALLBACK — wanted "
-                 f"{sharded['requested_mesh']}, "
-                 f"{sharded['devices']} devices)"
-                 if sharded["mesh_fallback"] else "")
-              + f"  fleet={sharded['fleet']} "
+              f"  fleet={sharded['fleet']} "
               f"{sharded['candidates_per_s']:8.1f} cand/s  "
               f"fleet steps {mt['serve.steps']['value']}  "
               f"|dp vs unsharded| "

@@ -51,6 +51,7 @@ from repro.core.dti import (PromptStats, batch_prompts, build_sliding_prompts,
 from repro.core.metrics import ctr_metrics
 from repro.data.requests import make_event_stream, warm_histories
 from repro.data.synthetic import make_ctr_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_params
 from repro.obs.trace import SpanTracer, validate_chrome_trace
 from repro.serve.engine import make_prefill_fn
@@ -357,4 +358,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the CLI run only: tests call main() in-process with the cache off
+    enable_compile_cache()
     main()

@@ -211,7 +211,9 @@ def attention_blocked(
 ) -> jax.Array:
     """Block-local windowed attention; semantics == attention_dense.
 
-    Requires Sq == Sk == S, S % window == 0, window > 0. Each query block i
+    Requires Sq == Sk == S and window > 0; S is padded up to a multiple of
+    the window (padded keys are invalid, padded query rows are dropped).
+    Each query block i
     attends kv blocks {i-1, i}; the (pos_q - pos_k <= window) mask inside the
     pair keeps semantics exact. Packed rows keep the block-pair invariant:
     positions restart per segment and segments are contiguous, so physical
@@ -231,7 +233,20 @@ def attention_blocked(
     if scale is None:
         scale = dqk ** -0.5
     blk = window
-    assert s % blk == 0, f"seq {s} not divisible by window {blk}"
+    if s % blk:
+        def pad(x, fill=0):
+            if x is None:
+                return None
+            widths = [(0, 0), (0, blk - s % blk)] + [(0, 0)] * (x.ndim - 2)
+            return jnp.pad(x, widths, constant_values=fill)
+        valid = jnp.ones((b, s), bool) if valid_k is None else valid_k
+        return attention_blocked(
+            pad(q), pad(k), pad(v), pos_q=pad(pos_q), pos_k=pad(pos_k),
+            window=window, is_sum_q=pad(is_sum_q), is_sum_k=pad(is_sum_k),
+            valid_k=pad(valid, False), seg_q=pad(seg_q), seg_k=pad(seg_k),
+            q_nope=pad(q_nope), k_nope=pad(k_nope), alibi=alibi, v0=pad(v0),
+            reset=reset, sum_isolated=sum_isolated, scale=scale,
+            q_chunk=q_chunk)[:, :s]
     nb = s // blk
 
     k_r = _repeat_kv(k, n_rep)

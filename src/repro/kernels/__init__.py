@@ -4,8 +4,10 @@
 """Shared kernel-package helpers.
 
 Every kernel wrapper defaults ``interpret`` the same way: compile to Mosaic
-on TPU, fall back to the Pallas interpreter elsewhere so the kernel *body*
-(not a jnp re-implementation) is what runs — and is tested — on CPU.
+when the default backend is a TPU, and run the Pallas interpreter on any
+other backend, so the kernel *body* (not a jnp re-implementation) is what
+runs — and is tested — on CPU. A backend that fails to start raises here;
+it is never read as "not a TPU".
 """
 from __future__ import annotations
 
@@ -15,10 +17,7 @@ import jax
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def default_interpret(interpret: Optional[bool] = None) -> bool:

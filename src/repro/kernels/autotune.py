@@ -86,10 +86,10 @@ def train_block(seq: int, head_dim: int, *,
                 interpret: Optional[bool] = None) -> int:
     """Band tile for the windowed training kernel at sequence ``seq``.
 
-    Interpret mode returns the historic 256 (``choose_block`` degrades it
-    toward a divisor of ragged lengths downstream, exactly as before). On
-    TPU: measured winner if any, else the largest candidate the sequence
-    and VMEM budget warrant.
+    Interpret mode returns the historic 256 (``choose_block`` takes a
+    shorter sequence whole and pads a longer ragged one to a block
+    multiple). On TPU: measured winner if any, else the largest candidate
+    the sequence and VMEM budget warrant.
     """
     interpret = default_interpret(interpret)
     if interpret:

@@ -26,11 +26,14 @@ scratch. Two structural wins over the dense decode path:
   window, in-burst segment) are index arithmetic against the staged
   (blk,) ``pos``/``seg`` tiles.
 
-Cache-native layout: K/V tiles are staged directly from the serving cache
-layout ``(B, cap, Hk, D)`` via index maps (query head h reads kv head
-``h // n_rep``) — no transpose or head replication in memory, mirroring
-the windowed training kernel. MLA runs through the same kernel in absorbed
-MQA form (Hk=1): the engine folds q through W_UK and concatenates the
+Cache-native layout: K/V tiles are staged from a free ``(B, cap, Hk*D)``
+view of the serving cache via index maps (query head h reads the lane
+block of kv head ``h // n_rep``) — no transpose or head replication of
+K/V in memory, mirroring the windowed training kernel. Only the small
+query/output and scale-sidecar tensors are transposed, so that every
+block meets Mosaic's (8, 128) tiling rule (docs/kernels.md). MLA runs
+through the same kernel in absorbed MQA form (Hk=1): the engine folds q
+through W_UK and concatenates the
 latent/rope streams so ``Dqk = r_kv + d_rope`` while values stay in the
 latent (``Dv = r_kv != Dqk``); see `repro.serve.engine._mla_decode_layer`.
 
@@ -96,11 +99,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.windowed_attn.windowed_attn import NEG_INF
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
-
-
 class DecodeStatics(NamedTuple):
     """Hashable per-call configuration of the decode kernel."""
     window: int          # 0 = unlimited (decode convention)
@@ -110,6 +108,7 @@ class DecodeStatics(NamedTuple):
     use_nope: bool       # SUM rows score the NoPE+ALiBi stream
     quant: bool          # int8 KV codes + fp32 scales; dequant in VMEM
     rope_start: int      # first key dim RoPE rotates (quant path only)
+    kv_heads: int        # Hk: K/V are staged from a (B, cap, Hk*D) view
     interpret: bool
 
 
@@ -119,6 +118,7 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, seg_q_ref, seg_k_ref, alibi_ref,
             m_ref, l_ref, acc_ref,
             *, n_kv: int, window: int, scale: float,
             use_seg: bool, use_nope: bool, quant: bool, rope_start: int):
+    hi = pl.program_id(1)
     ikv = pl.program_id(2)
 
     @pl.when(ikv == 0)
@@ -127,14 +127,16 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, seg_q_ref, seg_k_ref, alibi_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos_k = pos_k_ref[0]                                   # (blk,) int32
+    # per-row operands arrive as lane-major (1, n) rows; query-side ones are
+    # transposed to (s, 1) columns so every mask term broadcasts 2-D
+    pos_k = pos_k_ref[0]                                   # (1, blk) int32
 
     # occupancy skip: an all-empty cache block (padding, or capacity the
     # row never reached) contributes nothing — skip its matmuls entirely
     @pl.when(jnp.any(pos_k >= 0))
     def _block():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (s, Dqk)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (blk, Dqk)
+        q = q_ref[0, 0].astype(jnp.float32)                # (s, Dqk)
+        k = k_ref[0].astype(jnp.float32)                   # (blk, Dqk)
         kn = None
         if quant:
             # int8 path: the staged k tile is raw *unroped* codes. Build
@@ -146,7 +148,7 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, seg_q_ref, seg_k_ref, alibi_ref,
             # rotation is within the group) — scaling last keeps one
             # multiply off the trig path.
             dk = k.shape[-1]
-            sc = ks_ref[0, :, 0, :]                        # (blk, G)
+            sc = ks_ref[0, 0].T                            # (blk, G)
             if sc.shape[-1] == 1:
                 sc_vec = sc
             else:
@@ -155,8 +157,8 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, seg_q_ref, seg_k_ref, alibi_ref,
                                    sc[:, 0:1], sc[:, 1:2])
             if use_nope:
                 kn = k * sc_vec                            # unroped dequant
-            p = jnp.maximum(pos_k, 0).astype(jnp.float32)
-            ang = p[:, None] * rinv_ref[...][None, :]      # (blk, span/2)
+            p = jnp.maximum(pos_k, 0).astype(jnp.float32).T    # (blk, 1)
+            ang = p * rinv_ref[...]                        # (blk, span/2)
             cosv, sinv = jnp.cos(ang), jnp.sin(ang)
             span = k[:, rope_start:]
             half = span.shape[-1] // 2
@@ -169,49 +171,48 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, seg_q_ref, seg_k_ref, alibi_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
 
-        pos_q = pos_q_ref[0]                               # (s,) int32
-        d = pos_q[:, None] - pos_k[None, :]                # (s, blk)
+        pos_q = pos_q_ref[0].T                             # (s, 1) int32
+        d = pos_q - pos_k                                  # (s, blk)
         if use_nope:
-            qn = qn_ref[0, :, 0, :].astype(jnp.float32)
+            qn = qn_ref[0, 0].astype(jnp.float32)
             if not quant:
-                kn = kn_ref[0, :, 0, :].astype(jnp.float32)
+                kn = kn_ref[0].astype(jnp.float32)
             sn = jax.lax.dot_general(qn, kn, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-            sn = sn * scale - alibi_ref[0] * d.astype(jnp.float32)
-            s = jnp.where((sum_q_ref[0] != 0)[:, None], sn, s)
+            sn = sn * scale - alibi_ref[hi] * d.astype(jnp.float32)
+            s = jnp.where(sum_q_ref[0].T != 0, sn, s)
 
         # mask: filled slot + causal (+ window) (+ in-burst segment)
-        mask = (pos_k >= 0)[None, :] & (d >= 0)
+        mask = (pos_k >= 0) & (d >= 0)
         if window > 0:
             mask &= d <= window
         if use_seg:
             seg_k = seg_k_ref[0]
-            mask &= ((seg_k < 0)[None, :]
-                     | (seg_k[None, :] == seg_q_ref[0][:, None]))
+            mask &= (seg_k < 0) | (seg_k == seg_q_ref[0].T)
         s = jnp.where(mask, s, NEG_INF)
 
         # online softmax across the kv blocks
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_ref[...]                                # (s, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        w = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(w, axis=-1)
-        m_ref[:, 0] = m_new
+        w = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1, keepdims=True)
+        m_ref[...] = m_new
 
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # (blk, Dv)
+        v = v_ref[0].astype(jnp.float32)                   # (blk, Dv)
         if quant:
-            v = v * vs_ref[0, :, 0, :]                     # (blk, 1) scale
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+            v = v * vs_ref[0, 0].T                         # (blk, 1) scale
+        acc_ref[...] = (acc_ref[...] * alpha
                         + jax.lax.dot_general(
                             w, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
 
     @pl.when(ikv == n_kv - 1)
     def _finish():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         safe = jnp.where(l > 0, l, 1.0)
         # rows with no attendable key output exactly 0 (dense any_ok guard)
-        o_ref[0, :, 0, :] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def _pad_cap(x: jax.Array, cap_pad: int, fill) -> jax.Array:
@@ -261,12 +262,11 @@ def prepare_decode_inputs(
     same codes dequantized without rotation.
     """
     b, s_len, h, d = q.shape
-    cap = k.shape[1]
+    cap, hk = k.shape[1], k.shape[2]
     if scale is None:
         scale = d ** -0.5
-    # unlike choose_block (which degrades towards gcd -> 1 on ragged
-    # lengths), pad the cache operands up to a block multiple: the
-    # scheduler's capacity is arbitrary and padding slots are skipped
+    # pad the cache operands up to a block multiple: the scheduler's
+    # capacity is arbitrary and padding slots (pos = -1) are skipped
     blk = min(block_size, cap)
     cap_pad = ((cap + blk - 1) // blk) * blk
 
@@ -278,38 +278,48 @@ def prepare_decode_inputs(
             "quantized decode derives the NoPE stream from the codes"
     use_nope = q_nope is not None and sum_q is not None
     use_seg = seg_q is not None and seg_k is not None
-    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
-    sum_q_i = i32(sum_q if sum_q is not None else jnp.zeros((b, s_len)))
-    seg_q_i = i32(seg_q if use_seg else jnp.zeros((b, s_len)))
-    seg_k_i = i32(seg_k if use_seg else jnp.zeros((b, cap)))
+
+    def row(x, fill=None):              # (B, n) -> lane-major (B, 1, n)
+        x = jnp.asarray(x, jnp.int32)
+        return (x if fill is None else _pad_cap(x, cap_pad, fill))[:, None]
+
+    def heads(x):                       # (B, s, H, D) -> (B, H, s, D)
+        return jnp.swapaxes(x, 1, 2)
+
+    def kv(x):                          # (B, cap, Hk, D) -> (B, cap_pad, Hk*D)
+        x = _pad_cap(x, cap_pad, 0)
+        return x.reshape(b, cap_pad, -1)
+
+    def side(x):                        # (B, cap, Hk, G) -> (B, Hk, G, cap_pad)
+        return jnp.transpose(_pad_cap(x.astype(jnp.float32), cap_pad, 0),
+                             (0, 2, 3, 1))
+
+    zeros_q = jnp.zeros((b, s_len), jnp.int32)
     alibi_f = (alibi if alibi is not None
                else jnp.zeros((h,))).astype(jnp.float32)
     # without the NoPE stream the kernel never reads qn/kn: stage single-
     # element placeholders (their BlockSpecs shrink to match) instead of a
     # full-capacity zero tensor per layer per step
-    qn = q_nope if use_nope else jnp.zeros((b, 1, 1, 1), q.dtype)
     use_kn = use_nope and not quant
-    kn = k_nope if use_kn else jnp.zeros((b, 1, 1, 1), k.dtype)
+    one = jnp.zeros((b, 1, 1, 1), jnp.float32)
+    qn = heads(q_nope) if use_nope else one.astype(q.dtype)
+    kn = kv(k_nope) if use_kn else one[:, 0].astype(k.dtype)
     # scale sidecars: padded slots get scale 0 (their pos = -1 already
     # makes them unattendable; 0-scale dequant is exact zeros either way)
-    ks = (k_scale.astype(jnp.float32) if quant
-          else jnp.zeros((b, 1, 1, 1), jnp.float32))
-    vs = (v_scale.astype(jnp.float32)[..., None] if quant
-          else jnp.zeros((b, 1, 1, 1), jnp.float32))
-    rinv = (rope_inv.astype(jnp.float32) if quant
-            else jnp.zeros((1,), jnp.float32))
+    ks = side(k_scale) if quant else one
+    vs = side(v_scale[..., None]) if quant else one
+    rinv = (rope_inv.astype(jnp.float32)[None] if quant
+            else jnp.zeros((1, 1), jnp.float32))
 
-    arrays = (pos_q.astype(jnp.int32),
-              _pad_cap(pos_k.astype(jnp.int32), cap_pad, -1),
-              sum_q_i, seg_q_i, _pad_cap(seg_k_i, cap_pad, -1),
-              alibi_f, q, _pad_cap(k, cap_pad, 0), _pad_cap(v, cap_pad, 0),
-              qn, _pad_cap(kn, cap_pad, 0) if use_kn else kn,
-              _pad_cap(ks, cap_pad, 0) if quant else ks,
-              _pad_cap(vs, cap_pad, 0) if quant else vs,
-              rinv)
+    arrays = (row(pos_q), row(pos_k, -1),
+              row(sum_q if sum_q is not None else zeros_q),
+              row(seg_q if use_seg else zeros_q),
+              row(seg_k if use_seg else jnp.zeros((b, cap)), -1),
+              alibi_f, heads(q), kv(k), kv(v), qn, kn, ks, vs, rinv)
     st = DecodeStatics(window=int(window), scale=float(scale), block=blk,
                        use_seg=use_seg, use_nope=use_nope,
                        quant=quant, rope_start=int(rope_start),
+                       kv_heads=int(hk),
                        interpret=bool(interpret))
     return st, arrays
 
@@ -317,38 +327,52 @@ def prepare_decode_inputs(
 def decode_attention_bshd(st: DecodeStatics, pos_q, pos_k, sum_q, seg_q,
                           seg_k, alibi, q, k, v, qn, kn, ks, vs,
                           rinv) -> jax.Array:
-    """Normalised forward over prepared operands: returns o (B, s, H, Dv)."""
-    b, s_len, h, d = q.shape
+    """Normalised forward over prepared operands: returns o (B, s, H, Dv).
+
+    Kernel layouts (``prepare_decode_inputs``): queries ``(B, H, s, D)``;
+    K/V as the free ``(B, cap, Hk*D)`` view, so a head is a ``D``-lane
+    block of the last axis; per-row ints as ``(B, 1, n)`` rows; scale
+    sidecars ``(B, Hk, G, cap)``; ALiBi slopes whole in SMEM.
+    """
+    b, h, s_len, d = q.shape
     cap = k.shape[1]
-    hk = k.shape[2]
-    dv = v.shape[-1]
+    hk = st.kv_heads
+    dv = v.shape[-1] // hk
     n_rep = h // hk
     blk = st.block
     assert cap % blk == 0, f"cap={cap} not divisible by block {blk}"
     n_kv = cap // blk
 
     def q_idx(bi, hi, ki):
-        return (bi, 0, hi, 0)
+        return (bi, hi, 0, 0)
 
     def kv_idx(bi, hi, ki):
-        return (bi, ki, hi // n_rep, 0)
+        return (bi, ki, hi // n_rep)
 
-    def kvh_idx(bi, hi, ki):              # for (B, cap, 1, D) nope caches
-        return (bi, ki, 0, 0)
+    def kvh_idx(bi, hi, ki):              # for single-head nope caches
+        return (bi, ki, 0)
+
+    def side_idx(bi, hi, ki):
+        return (bi, hi // n_rep, 0, ki)
 
     one = lambda bi, hi, ki: (bi, 0, 0, 0)    # single-element placeholders
     use_kn = st.use_nope and not st.quant
     qn_map = q_idx if st.use_nope else one
-    kn_map = one if not use_kn else (
-        kv_idx if kn.shape[2] == hk else kvh_idx)
-    qn_spec = ((1, s_len, 1, qn.shape[-1]) if st.use_nope else (1, 1, 1, 1))
-    kn_spec = ((1, blk, 1, kn.shape[-1]) if use_kn else (1, 1, 1, 1))
+    kn_map = (lambda bi, hi, ki: (bi, 0, 0)) if not use_kn else (
+        kv_idx if kn.shape[2] == hk * d else kvh_idx)
+    qn_spec = ((1, 1, s_len, qn.shape[-1]) if st.use_nope else (1, 1, 1, 1))
+    kn_spec = ((1, blk, d) if use_kn else (1, 1, 1))
     # quant sidecars ride the same kv-block schedule as k/v; the rope
     # inverse-frequency row is tiny and staged whole per grid step
-    ks_map = kv_idx if st.quant else one
-    vs_map = kv_idx if st.quant else one
-    ks_spec = ((1, blk, 1, ks.shape[-1]) if st.quant else (1, 1, 1, 1))
-    vs_spec = ((1, blk, 1, 1) if st.quant else (1, 1, 1, 1))
+    side_map = side_idx if st.quant else one
+    ks_spec = ((1, 1, ks.shape[2], blk) if st.quant else (1, 1, 1, 1))
+    vs_spec = ((1, 1, 1, blk) if st.quant else (1, 1, 1, 1))
+
+    def row_q(bi, hi, ki):
+        return (bi, 0, 0)
+
+    def row_k(bi, hi, ki):
+        return (bi, 0, ki)
 
     grid = (b, h, n_kv)
     out = pl.pallas_call(
@@ -358,34 +382,34 @@ def decode_attention_bshd(st: DecodeStatics, pos_q, pos_k, sum_q, seg_q,
                           rope_start=st.rope_start),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, s_len), lambda bi, hi, ki: (bi, 0)),   # pos_q
-            pl.BlockSpec((1, blk), lambda bi, hi, ki: (bi, ki)),    # pos_k
-            pl.BlockSpec((1, s_len), lambda bi, hi, ki: (bi, 0)),   # sum_q
-            pl.BlockSpec((1, s_len), lambda bi, hi, ki: (bi, 0)),   # seg_q
-            pl.BlockSpec((1, blk), lambda bi, hi, ki: (bi, ki)),    # seg_k
-            pl.BlockSpec((1,), lambda bi, hi, ki: (hi,)),           # alibi
-            pl.BlockSpec((1, s_len, 1, d), q_idx),                  # q
-            pl.BlockSpec((1, blk, 1, d), kv_idx),                   # k
-            pl.BlockSpec((1, blk, 1, dv), kv_idx),                  # v
+            pl.BlockSpec((1, 1, s_len), row_q),                     # pos_q
+            pl.BlockSpec((1, 1, blk), row_k),                       # pos_k
+            pl.BlockSpec((1, 1, s_len), row_q),                     # sum_q
+            pl.BlockSpec((1, 1, s_len), row_q),                     # seg_q
+            pl.BlockSpec((1, 1, blk), row_k),                       # seg_k
+            pl.BlockSpec(memory_space=pltpu.SMEM),                  # alibi
+            pl.BlockSpec((1, 1, s_len, d), q_idx),                  # q
+            pl.BlockSpec((1, blk, d), kv_idx),                      # k
+            pl.BlockSpec((1, blk, dv), kv_idx),                     # v
             pl.BlockSpec(qn_spec, qn_map),                          # qn
             pl.BlockSpec(kn_spec, kn_map),                          # kn
-            pl.BlockSpec(ks_spec, ks_map),                          # k scales
-            pl.BlockSpec(vs_spec, vs_map),                          # v scales
-            pl.BlockSpec((rinv.shape[0],), lambda bi, hi, ki: (0,)),  # rinv
+            pl.BlockSpec(ks_spec, side_map),                        # k scales
+            pl.BlockSpec(vs_spec, side_map),                        # v scales
+            pl.BlockSpec(rinv.shape, lambda bi, hi, ki: (0, 0)),    # rinv
         ],
-        out_specs=pl.BlockSpec((1, s_len, 1, dv), q_idx),
-        out_shape=jax.ShapeDtypeStruct((b, s_len, h, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, s_len, dv), q_idx),
+        out_shape=jax.ShapeDtypeStruct((b, h, s_len, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((s_len, 1), jnp.float32),      # m (row max)
             pltpu.VMEM((s_len, 1), jnp.float32),      # l (row denom)
             pltpu.VMEM((s_len, dv), jnp.float32),     # acc (value accum)
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=st.interpret,
     )(pos_q, pos_k, sum_q, seg_q, seg_k, alibi, q, k, v, qn, kn, ks, vs,
       rinv)
-    return out
+    return jnp.swapaxes(out, 1, 2)
 
 
 __all__ = ["DecodeStatics", "prepare_decode_inputs", "decode_attention_bshd"]

@@ -6,10 +6,10 @@ optional serve operands (SUM flags, in-burst segments, NoPE stream) to
 concrete arrays plus hashable statics and lowers to the fused Pallas
 kernel in ``decode_attn.py``. Differences from the training op:
 
-* operands stay in the serving cache layout — queries ``(B, s, H, Dqk)``,
+* operands arrive in the serving cache layout — queries ``(B, s, H, Dqk)``,
   cache-side tensors ``(B, cap, Hk, D)`` — and the index maps do the GQA
-  head-group addressing, so no transposes or head replication happen in
-  memory;
+  head-group addressing on a free ``(B, cap, Hk*D)`` view, so K/V are
+  never transposed or head-replicated in memory;
 * no VJP: decode never trains, so the op is forward-only (scores are read
   under ``jax.lax.stop_gradient`` semantics by construction);
 * the capacity axis is padded to a kv-block multiple with ``pos = -1``
@@ -27,9 +27,11 @@ what CPU tests exercise).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import default_interpret
 from repro.kernels.autotune import decode_block
@@ -59,6 +61,7 @@ def decode_attention(
     v_scale: Optional[jax.Array] = None,    # (B, cap, Hk) fp32
     rope_start: int = 0,                    # first roped key dim (quant)
     rope_theta: float = 10000.0,
+    mesh=None,                              # (data, model) serving mesh
 ) -> jax.Array:
     """Fused burst attention into the batched KV cache -> (B, s, H, Dv).
 
@@ -68,7 +71,32 @@ def decode_attention(
     mode). ``k_scale`` switches to the quantized-KV contract: ``k``/``v``
     are raw int8 cache codes and dequant + RoPE (span ``[rope_start:]``,
     base ``rope_theta``) happen inside the kernel — see docs/kernels.md.
+
+    ``mesh`` runs the kernel once per shard under ``shard_map`` (GSPMD
+    cannot partition a Mosaic kernel): cache rows over ``data`` and KV
+    heads — with their query-head groups — over ``model``, each axis only
+    where it divides; an axis that does not divide stays replicated.
     """
+    if mesh is not None:
+        operands = dict(q=q, k=k, v=v, pos_q=pos_q, pos_k=pos_k,
+                        is_sum_q=is_sum_q, q_nope=q_nope, k_nope=k_nope,
+                        alibi=alibi, seg_q=seg_q, seg_k=seg_k,
+                        k_scale=k_scale, v_scale=v_scale)
+        operands = {n: x for n, x in operands.items() if x is not None}
+        row = "data" if q.shape[0] % mesh.shape["data"] == 0 else None
+        head = "model" if k.shape[2] % mesh.shape["model"] == 0 else None
+        specs = {n: (P(head) if n == "alibi" else
+                     P(row, None) if x.ndim == 2 else
+                     P(row, None, head, *[None] * (x.ndim - 3)))
+                 for n, x in operands.items()}
+        local = functools.partial(
+            decode_attention, window=window, scale=scale,
+            block_size=block_size, interpret=interpret,
+            rope_start=rope_start, rope_theta=rope_theta)
+        return jax.shard_map(lambda ops: local(**ops), mesh=mesh,
+                             in_specs=(specs,),
+                             out_specs=P(row, None, head, None),
+                             check_vma=False)(operands)
     interpret = default_interpret(interpret)
     if block_size is None:
         block_size = decode_block(k.shape[1], dqk=q.shape[-1],

@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(ids_ref, w_ref, table_ref, o_ref, *, bag: int):
     i = pl.program_id(0)
@@ -56,7 +53,7 @@ def embedding_bag_pallas(table: jax.Array,      # (V, D)
             out_specs=pl.BlockSpec((1, d), lambda i, ids, w: (i // bag, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(ids.reshape(-1).astype(jnp.int32),

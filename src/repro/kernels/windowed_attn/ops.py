@@ -100,7 +100,8 @@ def windowed_attention(
                if use_reset else None),
         sum_isolated=sum_isolated and is_sum_k is not None,
         scale=scale, block_size=block_size, interpret=interpret)
-    return jnp.swapaxes(_attn(st, *arrays), 1, 2)
+    out = _attn(st, *arrays)[:, :, :q.shape[1]]      # drop padded rows
+    return jnp.swapaxes(out, 1, 2)
 
 
 __all__ = ["windowed_attention"]

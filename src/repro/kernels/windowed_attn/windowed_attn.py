@@ -21,7 +21,7 @@ All mask/positional inputs are int32 (pos) / int32 (flags) so the kernel
 has no sub-byte loads. GQA is handled by index-mapping query head h onto
 kv head h // n_rep — K/V are never repeated in memory.
 
-The forward also emits the per-row softmax logsumexp (B, H, S) — the flash
+The forward also emits the per-row softmax logsumexp (B, H, 1, S) — the flash
 residual the backward kernels (``windowed_attn_bwd``) use to recompute
 probabilities blockwise instead of storing them; see docs/kernels.md for
 the fwd/bwd contract.
@@ -29,16 +29,12 @@ the fwd/bwd contract.
 from __future__ import annotations
 
 import functools
-import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -59,14 +55,13 @@ class AttnStatics(NamedTuple):
     interpret: bool
 
 
-def choose_block(s: int, block_size: int) -> int:
-    """Largest block <= block_size that divides S. Falls back to
-    gcd(S, block_size) so arbitrary row lengths stay legal (correctness
-    fallback — pick 128-aligned S on real TPUs)."""
+def choose_block(s: int, block_size: int) -> Tuple[int, int]:
+    """-> (block, padded S). One block when S fits in ``block_size``;
+    otherwise ``block_size`` tiles over S padded up to a block multiple.
+    Mosaic only tiles a sequence axis in lane-aligned blocks (or takes it
+    whole), so ragged S is padded rather than cut into gcd-sized blocks."""
     blk = min(block_size, s)
-    if s % blk:
-        blk = math.gcd(s, blk)
-    return blk
+    return blk, -(-s // blk) * blk
 
 
 def n_kv_blocks(window: int, blk: int, n_q: int) -> int:
@@ -99,47 +94,49 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    pos_q = pos_q_ref[0]                                  # (blk,) int32
-    pos_k = pos_k_ref[0]
-    d = pos_q[:, None] - pos_k[None, :]                   # (blk, blk)
-    sum_q = sum_q_ref[0] != 0                             # (blk,)
+    # per-row operands arrive as (1, blk) rows; query-side ones are
+    # transposed to (blk, 1) columns so every mask term broadcasts 2-D
+    pos_q = pos_q_ref[0].T                                # (blk, 1) int32
+    pos_k = pos_k_ref[0]                                  # (1, blk)
+    d = pos_q - pos_k                                     # (blk, blk)
+    sum_q = sum_q_ref[0].T != 0                           # (blk, 1)
 
     if use_nope:
         qn = qn_ref[0, 0].astype(jnp.float32)
         kn = kn_ref[0, 0].astype(jnp.float32)
         sn = jax.lax.dot_general(qn, kn, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        sn = sn - alibi_ref[0] * d.astype(jnp.float32)
-        s = jnp.where(sum_q[:, None], sn, s)
+        sn = sn - alibi_ref[pl.program_id(1)] * d.astype(jnp.float32)
+        s = jnp.where(sum_q, sn, s)
 
     # mask: causal + window + key-padding (+ SUM isolation) (+ same packed
     # segment) + real kv block
-    mask = (d >= 0) & (d <= window) & (valid_k_ref[0] != 0)[None, :]
+    mask = (d >= 0) & (d <= window) & (valid_k_ref[0] != 0)
     if sum_isolated:
-        mask &= (sum_k_ref[0] == 0)[None, :] | (d == 0)
+        mask &= (sum_k_ref[0] == 0) | (d == 0)
     if use_seg:
-        mask &= seg_q_ref[0][:, None] == seg_k_ref[0][None, :]
+        mask &= seg_q_ref[0].T == seg_k_ref[0]
     j_actual = iq - (n_kv - 1) + ikv
     mask &= j_actual >= 0                                  # clamped block
     s = jnp.where(mask, s, NEG_INF)
 
     # online softmax
-    m_prev = m_ref[:, 0]                                   # (blk,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_prev = m_ref[...]                                    # (blk, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    w = jnp.exp(s - m_new[:, None])
+    w = jnp.exp(s - m_new)
     w = jnp.where(mask, w, 0.0)
-    l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(w, axis=-1)
-    m_ref[:, 0] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1, keepdims=True)
+    m_ref[...] = m_new
 
     v = v_ref[0, 0].astype(jnp.float32)
-    acc = acc_ref[...] * alpha[:, None]
+    acc = acc_ref[...] * alpha
     acc += jax.lax.dot_general(w, v, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
     if use_reset:
         a = y_min + (y_max - y_min) * jax.nn.sigmoid(
             d.astype(jnp.float32) - midpoint)
-        wr = w * a * sum_q[:, None].astype(jnp.float32)
+        wr = w * a * sum_q.astype(jnp.float32)
         dv = v0_ref[0, 0].astype(jnp.float32) - v
         acc += jax.lax.dot_general(wr, dv, (((1,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32)
@@ -147,13 +144,13 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
 
     @pl.when(ikv == n_kv - 1)
     def _finish():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0, ...] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0, ...] = (acc_ref[...] / safe).astype(o_ref.dtype)
         # flash residual: rows with no attendable key get +1e30 so the bwd
         # recompute exp(s - lse) underflows to exactly 0 for every key
-        lse_ref[0, 0, :] = jnp.where(l > 0, m_ref[:, 0] + jnp.log(safe),
-                                     -NEG_INF)
+        lse_ref[0, 0] = jnp.where(l > 0, m_ref[...] + jnp.log(safe),
+                                  -NEG_INF).T
 
 
 def prepare_inputs(
@@ -183,29 +180,37 @@ def prepare_inputs(
 
     The array tuple is exactly the differentiable-argument order of the
     custom_vjp in ops.py: (q, k, v, qn, kn, v0, alibi, pos_q, pos_k,
-    sum_q, sum_k, valid_k, seg_q, seg_k).
+    sum_q, sum_k, valid_k, seg_q, seg_k). The sequence axis is padded to
+    a block multiple (padded keys carry ``valid_k = 0``; the caller drops
+    the padded query rows), and every per-row int operand is laid out as
+    ``(B, 1, S_pad)`` so its kernel block ``(1, blk)`` is lane-major.
     """
     b, h, s, d = q.shape
-    blk = choose_block(s, block_size)
+    blk, s_pad = choose_block(s, block_size)
     if scale is None:
         scale = d ** -0.5
 
     use_nope = q_nope is not None
     use_reset = reset is not None and v0 is not None
     use_seg = seg_q is not None and seg_k is not None
-    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
-    sum_q_i = i32(sum_q if sum_q is not None else jnp.zeros((b, s)))
-    sum_k_i = i32(sum_k if sum_k is not None else jnp.zeros((b, s)))
-    valid_i = i32(valid_k if valid_k is not None else jnp.ones((b, s)))
-    seg_q_i = i32(seg_q if use_seg else jnp.zeros((b, s)))
-    seg_k_i = i32(seg_k if use_seg else jnp.zeros((b, s)))
+
+    def seq(x):                         # pad axis 2 of a (B, H, S, D) array
+        return jnp.pad(x, ((0, 0), (0, 0), (0, s_pad - s), (0, 0)))
+
+    def row(x, fill=0):                 # (B, S) -> (B, 1, S_pad) int32
+        x = jnp.asarray(x, jnp.int32)
+        return jnp.pad(x, ((0, 0), (0, s_pad - s)),
+                       constant_values=fill)[:, None, :]
+
+    zeros = jnp.zeros((b, s), jnp.int32)
     alibi_f = (alibi if alibi is not None
                else jnp.zeros((h,))).astype(jnp.float32)
     # value dim may differ from the qk dim (MLA: v_head_dim != qk_head)
-    zero_qk = jnp.zeros((b, 1, s, d), q.dtype)
-    qn = q_nope if use_nope else zero_qk
-    kn = k_nope if use_nope else zero_qk
-    v0_ = v0 if use_reset else jnp.zeros((b, 1, s, v.shape[-1]), q.dtype)
+    zero_qk = jnp.zeros((b, 1, s_pad, d), q.dtype)
+    qn = seq(q_nope) if use_nope else zero_qk
+    kn = seq(k_nope) if use_nope else zero_qk
+    v0_ = (seq(v0) if use_reset
+           else jnp.zeros((b, 1, s_pad, v.shape[-1]), q.dtype))
     y_min, y_max, midpoint = reset if use_reset else (0.0, 0.0, 0.0)
 
     st = AttnStatics(window=int(window), scale=float(scale), block=blk,
@@ -213,9 +218,13 @@ def prepare_inputs(
                      use_nope=use_nope, use_reset=use_reset,
                      y_min=float(y_min), y_max=float(y_max),
                      midpoint=float(midpoint), interpret=bool(interpret))
-    arrays = (q, k, v, qn, kn, v0_, alibi_f,
-              pos_q.astype(jnp.int32), pos_k.astype(jnp.int32),
-              sum_q_i, sum_k_i, valid_i, seg_q_i, seg_k_i)
+    arrays = (seq(q), seq(k), seq(v), qn, kn, v0_, alibi_f,
+              row(pos_q), row(pos_k),
+              row(sum_q if sum_q is not None else zeros),
+              row(sum_k if sum_k is not None else zeros),
+              row(valid_k if valid_k is not None else zeros + 1),
+              row(seg_q if use_seg else zeros),
+              row(seg_k if use_seg else zeros))
     return st, arrays
 
 
@@ -223,7 +232,7 @@ def windowed_attention_fwd_bhsd(
         st: AttnStatics, q, k, v, qn, kn, v0, alibi,
         pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Normalised forward: returns (o (B,H,S,Dv), lse (B,H,S) fp32)."""
+    """Normalised forward: returns (o (B,H,S,Dv), lse (B,H,1,S) fp32)."""
     b, h, s, d = q.shape
     dv = v.shape[-1]
     hk = k.shape[1]
@@ -241,16 +250,17 @@ def windowed_attention_fwd_bhsd(
         j = qi - (n_kv - 1) + ki
         return (bi, 0, jnp.maximum(j, 0), 0)
 
-    def seq_q_idx(bi, hi, qi, ki):
-        return (bi, qi)
+    def q_idx(bi, hi, qi, ki):
+        return (bi, hi, qi, 0)
 
-    def seq_k_idx(bi, hi, qi, ki):
-        j = qi - (n_kv - 1) + ki
-        return (bi, jnp.maximum(j, 0))
+    def row_q_idx(bi, hi, qi, ki):
+        return (bi, 0, qi)
+
+    def row_k_idx(bi, hi, qi, ki):
+        return (bi, 0, jnp.maximum(qi - (n_kv - 1) + ki, 0))
 
     kn_map = kv_idx if st.use_nope and kn.shape[1] == hk else kvh_idx
-    qn_map = ((lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-              if st.use_nope else kvh_idx)
+    qn_map = q_idx if st.use_nope else kvh_idx
     v0_map = kv_idx if st.use_reset else kvh_idx
 
     grid = (b, h, n_q, n_kv)
@@ -262,16 +272,15 @@ def windowed_attention_fwd_bhsd(
             y_max=st.y_max, midpoint=st.midpoint),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk), seq_q_idx),                  # pos_q
-            pl.BlockSpec((1, blk), seq_k_idx),                  # pos_k
-            pl.BlockSpec((1, blk), seq_q_idx),                  # sum_q
-            pl.BlockSpec((1, blk), seq_k_idx),                  # sum_k
-            pl.BlockSpec((1, blk), seq_k_idx),                  # valid_k
-            pl.BlockSpec((1, blk), seq_q_idx),                  # seg_q
-            pl.BlockSpec((1, blk), seq_k_idx),                  # seg_k
-            pl.BlockSpec((1,), lambda bi, hi, qi, ki: (hi,)),   # alibi
-            pl.BlockSpec((1, 1, blk, d),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),  # q
+            pl.BlockSpec((1, 1, blk), row_q_idx),               # pos_q
+            pl.BlockSpec((1, 1, blk), row_k_idx),               # pos_k
+            pl.BlockSpec((1, 1, blk), row_q_idx),               # sum_q
+            pl.BlockSpec((1, 1, blk), row_k_idx),               # sum_k
+            pl.BlockSpec((1, 1, blk), row_k_idx),               # valid_k
+            pl.BlockSpec((1, 1, blk), row_q_idx),               # seg_q
+            pl.BlockSpec((1, 1, blk), row_k_idx),               # seg_k
+            pl.BlockSpec(memory_space=pltpu.SMEM),              # alibi (H,)
+            pl.BlockSpec((1, 1, blk, d), q_idx),                # q
             pl.BlockSpec((1, 1, blk, d), kv_idx),               # k
             pl.BlockSpec((1, 1, blk, dv), kv_idx),              # v
             pl.BlockSpec((1, 1, blk, d), qn_map),               # qn
@@ -279,20 +288,20 @@ def windowed_attention_fwd_bhsd(
             pl.BlockSpec((1, 1, blk, dv), v0_map),              # v0
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, blk, dv),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, blk), lambda bi, hi, qi, ki: (bi, hi, qi)),
+            pl.BlockSpec((1, 1, blk, dv), q_idx),
+            pl.BlockSpec((1, 1, 1, blk),
+                         lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk, 1), jnp.float32),      # m (row max)
             pltpu.VMEM((blk, 1), jnp.float32),      # l (row denom)
             pltpu.VMEM((blk, dv), jnp.float32),     # acc (value accum)
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=st.interpret,
@@ -301,41 +310,5 @@ def windowed_attention_fwd_bhsd(
     return out, lse
 
 
-def windowed_attention_bhsd(
-    q: jax.Array,                 # (B, H, S, D)   RoPE'd queries
-    k: jax.Array,                 # (B, Hk, S, D)  RoPE'd keys
-    v: jax.Array,                 # (B, Hk, S, D)
-    pos_q: jax.Array,             # (B, S) int32
-    pos_k: jax.Array,             # (B, S) int32
-    *,
-    window: int,
-    sum_q: Optional[jax.Array] = None,     # (B, S) int32 flags
-    sum_k: Optional[jax.Array] = None,
-    valid_k: Optional[jax.Array] = None,
-    seg_q: Optional[jax.Array] = None,     # (B, S) int32 packed segments
-    seg_k: Optional[jax.Array] = None,
-    q_nope: Optional[jax.Array] = None,    # (B, H, S, D)
-    k_nope: Optional[jax.Array] = None,    # (B, Hk, S, D)
-    alibi: Optional[jax.Array] = None,     # (H,) f32
-    v0: Optional[jax.Array] = None,        # (B, Hk, S, D)
-    reset: Optional[tuple] = None,         # (y_min, y_max, midpoint)
-    sum_isolated: bool = True,
-    scale: Optional[float] = None,
-    block_size: int = 256,
-    interpret: bool = False,
-    return_residuals: bool = False,
-):
-    """Raw forward (no VJP) — ``ops.windowed_attention`` is the trainable
-    entry point. ``return_residuals=True`` also returns the per-row lse."""
-    st, arrays = prepare_inputs(
-        q, k, v, pos_q, pos_k, window=window, sum_q=sum_q, sum_k=sum_k,
-        valid_k=valid_k, seg_q=seg_q, seg_k=seg_k, q_nope=q_nope,
-        k_nope=k_nope, alibi=alibi, v0=v0, reset=reset,
-        sum_isolated=sum_isolated, scale=scale, block_size=block_size,
-        interpret=interpret)
-    out, lse = windowed_attention_fwd_bhsd(st, *arrays)
-    return (out, lse) if return_residuals else out
-
-
 __all__ = ["AttnStatics", "choose_block", "n_kv_blocks", "prepare_inputs",
-           "windowed_attention_fwd_bhsd", "windowed_attention_bhsd"]
+           "windowed_attention_fwd_bhsd"]

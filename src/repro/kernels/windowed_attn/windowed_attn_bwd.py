@@ -39,7 +39,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.windowed_attn.windowed_attn import (AttnStatics,
-                                                       _CompilerParams,
                                                        n_kv_blocks)
 
 _f32 = jnp.float32
@@ -56,39 +55,41 @@ def _recompute_tile(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
                     use_reset, y_min, y_max, midpoint):
     """Shared (q-block, kv-block) tile math for both backward passes.
 
-    Returns (p, ds_rope, ds_nope, asig): probabilities, the score gradient
-    split by stream (RoPE rows vs SUM NoPE rows), and the reset weight
-    a(d)*sigma (None unless the reset stream is live). All fp32.
+    Query-side rows (pos_q, sum_q, seg_q, lse, delta) arrive as (blk, 1)
+    columns and key-side ones as (1, blk) rows. Returns (p, ds_rope,
+    ds_nope, asig): probabilities, the score gradient split by stream (RoPE
+    rows vs SUM NoPE rows), and the reset weight a(d)*sigma (None unless
+    the reset stream is live). All fp32.
     """
     s = _dot(q, k, ((1,), (1,))) * scale                  # (blk_q, blk_k)
-    d = pos_q[:, None] - pos_k[None, :]
+    d = pos_q - pos_k
     sum_row = sum_q != 0
     if use_nope:
         sn = _dot(qn, kn, ((1,), (1,))) * scale
         sn = sn - alibi * d.astype(_f32)
-        s = jnp.where(sum_row[:, None], sn, s)
+        s = jnp.where(sum_row, sn, s)
 
-    mask = (d >= 0) & (d <= window) & (valid_k != 0)[None, :]
+    mask = (d >= 0) & (d <= window) & (valid_k != 0)
     if sum_isolated:
-        mask &= (sum_k == 0)[None, :] | (d == 0)
+        mask &= (sum_k == 0) | (d == 0)
     if use_seg:
-        mask &= seg_q[:, None] == seg_k[None, :]
+        mask &= seg_q == seg_k
     mask &= band_ok
 
     # p == softmax probs exactly: lse = m + log(l) (or +1e30 on empty rows,
     # in which case every exp underflows to 0 and so does delta)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
 
     dp = _dot(do, v, ((1,), (1,)))                        # do . v_j
     asig = None
     if use_reset:
         a = y_min + (y_max - y_min) * jax.nn.sigmoid(
             d.astype(_f32) - midpoint)
-        asig = a * sum_row[:, None].astype(_f32)
+        asig = a * sum_row.astype(_f32)
         dp = dp + asig * _dot(do, v0 - v, ((1,), (1,)))
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     if use_nope:
-        ds_nope = ds * sum_row[:, None].astype(_f32)
+        ds_nope = ds * sum_row.astype(_f32)
         ds_rope = ds - ds_nope
     else:
         ds_rope, ds_nope = ds, None
@@ -98,15 +99,16 @@ def _recompute_tile(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
 def _load_tile(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
                seg_q_ref, seg_k_ref, alibi_ref, q_ref, k_ref, v_ref,
                qn_ref, kn_ref, v0_ref, do_ref, lse_ref, delta_ref):
+    # query-side rows transpose to (blk, 1) columns, key-side stay (1, blk)
     return dict(
-        pos_q=pos_q_ref[0], pos_k=pos_k_ref[0], sum_q=sum_q_ref[0],
-        sum_k=sum_k_ref[0], valid_k=valid_k_ref[0], seg_q=seg_q_ref[0],
-        seg_k=seg_k_ref[0], alibi=alibi_ref[0],
+        pos_q=pos_q_ref[0].T, pos_k=pos_k_ref[0], sum_q=sum_q_ref[0].T,
+        sum_k=sum_k_ref[0], valid_k=valid_k_ref[0], seg_q=seg_q_ref[0].T,
+        seg_k=seg_k_ref[0], alibi=alibi_ref[pl.program_id(1)],
         q=q_ref[0, 0].astype(_f32), k=k_ref[0, 0].astype(_f32),
         qn=qn_ref[0, 0].astype(_f32), kn=kn_ref[0, 0].astype(_f32),
         v=v_ref[0, 0].astype(_f32), v0=v0_ref[0, 0].astype(_f32),
-        do=do_ref[0, 0].astype(_f32), lse=lse_ref[0, 0],
-        delta=delta_ref[0, 0])
+        do=do_ref[0, 0].astype(_f32), lse=lse_ref[0, 0].T,
+        delta=delta_ref[0, 0].T)
 
 
 def _dq_kernel(*refs, n_kv: int, use_nope: bool, scale: float, math_kw):
@@ -209,14 +211,15 @@ def windowed_attention_bwd_bhsd(
     kn_heads = kn.shape[1]
 
     # flash delta: D_i = <do_i, o_i> (holds with the reset stream too)
-    delta = jnp.sum(o.astype(_f32) * do.astype(_f32), axis=-1)  # (B,H,S)
+    delta = jnp.sum(o.astype(_f32) * do.astype(_f32),
+                    axis=-1)[:, :, None, :]                   # (B,H,1,S)
 
     math_kw = dict(window=st.window, scale=st.scale,
                    sum_isolated=st.sum_isolated, use_seg=st.use_seg,
                    use_nope=st.use_nope, use_reset=st.use_reset,
                    y_min=st.y_min, y_max=st.y_max, midpoint=st.midpoint)
-    sem = _CompilerParams(dimension_semantics=("parallel", "parallel",
-                                               "parallel", "arbitrary"))
+    sem = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel",
+                                                    "parallel", "arbitrary"))
     grid = (b, h, n_q, n_kv)
 
     # ---- dq pass: q-block major, walk the kv band (same maps as fwd) ----
@@ -232,14 +235,14 @@ def windowed_attention_bwd_bhsd(
         return (bi, hi, qi, 0)
 
     def seq_q_idx(bi, hi, qi, ki):
-        return (bi, qi)
+        return (bi, 0, qi)
 
     def seq_k_idx(bi, hi, qi, ki):
         j = qi - (n_kv - 1) + ki
-        return (bi, jnp.maximum(j, 0))
+        return (bi, 0, jnp.maximum(j, 0))
 
     def row_q_idx(bi, hi, qi, ki):
-        return (bi, hi, qi)
+        return (bi, hi, 0, qi)
 
     kn_map = kv_idx if st.use_nope and kn_heads == hk else kvh_idx
     qn_map = q_idx if st.use_nope else kvh_idx
@@ -247,14 +250,14 @@ def windowed_attention_bwd_bhsd(
 
     def in_specs(sq, sk, qm, km, vm, qnm, knm, v0m, rowm):
         return [
-            pl.BlockSpec((1, blk), sq),                     # pos_q
-            pl.BlockSpec((1, blk), sk),                     # pos_k
-            pl.BlockSpec((1, blk), sq),                     # sum_q
-            pl.BlockSpec((1, blk), sk),                     # sum_k
-            pl.BlockSpec((1, blk), sk),                     # valid_k
-            pl.BlockSpec((1, blk), sq),                     # seg_q
-            pl.BlockSpec((1, blk), sk),                     # seg_k
-            pl.BlockSpec((1,), lambda bi, hi, qi, ki: (hi,)),  # alibi
+            pl.BlockSpec((1, 1, blk), sq),                  # pos_q
+            pl.BlockSpec((1, 1, blk), sk),                  # pos_k
+            pl.BlockSpec((1, 1, blk), sq),                  # sum_q
+            pl.BlockSpec((1, 1, blk), sk),                  # sum_k
+            pl.BlockSpec((1, 1, blk), sk),                  # valid_k
+            pl.BlockSpec((1, 1, blk), sq),                  # seg_q
+            pl.BlockSpec((1, 1, blk), sk),                  # seg_k
+            pl.BlockSpec(memory_space=pltpu.SMEM),          # alibi (H,)
             pl.BlockSpec((1, 1, blk, d), qm),               # q
             pl.BlockSpec((1, 1, blk, d), km),               # k
             pl.BlockSpec((1, 1, blk, dv_d), vm),            # v
@@ -262,8 +265,8 @@ def windowed_attention_bwd_bhsd(
             pl.BlockSpec((1, 1, blk, d), knm),              # kn
             pl.BlockSpec((1, 1, blk, dv_d), v0m),           # v0
             pl.BlockSpec((1, 1, blk, dv_d), qm),            # do
-            pl.BlockSpec((1, 1, blk), rowm),                # lse
-            pl.BlockSpec((1, 1, blk), rowm),                # delta
+            pl.BlockSpec((1, 1, 1, blk), rowm),             # lse
+            pl.BlockSpec((1, 1, 1, blk), rowm),             # delta
         ]
 
     operands = (pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k, alibi,
@@ -297,10 +300,10 @@ def windowed_attention_bwd_bhsd(
         return (bi, 0, jnp.minimum(j + ib, n_q - 1), 0)
 
     def b_seq_q_idx(bi, hi, j, ib):
-        return (bi, jnp.minimum(j + ib, n_q - 1))
+        return (bi, 0, jnp.minimum(j + ib, n_q - 1))
 
     def b_seq_k_idx(bi, hi, j, ib):
-        return (bi, j)
+        return (bi, 0, j)
 
     def b_kv_idx(bi, hi, j, ib):
         return (bi, hi // n_rep, j, 0)
@@ -309,7 +312,7 @@ def windowed_attention_bwd_bhsd(
         return (bi, 0, j, 0)
 
     def b_row_idx(bi, hi, j, ib):
-        return (bi, hi, jnp.minimum(j + ib, n_q - 1))
+        return (bi, hi, 0, jnp.minimum(j + ib, n_q - 1))
 
     def b_out_idx(bi, hi, j, ib):
         return (bi, hi, j, 0)

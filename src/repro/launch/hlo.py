@@ -174,12 +174,9 @@ def count_op(hlo_text: str, opname: str) -> int:
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalised across jax versions: older
-    jax returns a one-element list of dicts, newer jax the dict itself."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()`` (a dict, or None when the backend
+    reports nothing)."""
+    return compiled.cost_analysis() or {}
 
 
 __all__ = ["collective_bytes", "analyze_hlo", "count_op",

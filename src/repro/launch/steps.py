@@ -37,7 +37,7 @@ from repro.sharding.partition import (batch_spec, make_param_specs, rules_for,
                                       zero1_specs)
 from repro.sparse.embedding import embedding_lookup
 from repro.train.optimizer import (OptimizerConfig, adamw_update,
-                                   init_opt_state)
+                                   freeze_non_lora, init_opt_state)
 from repro.train.trainer import TrainState
 
 SP = SpecialTokens()
@@ -75,15 +75,6 @@ def _sds(mesh, shape, dtype, *axes) -> jax.ShapeDtypeStruct:
 
 def _replicated_specs(tree: Any, mesh) -> Any:
     return jax.tree_util.tree_map(lambda _: NamedSharding(mesh, P()), tree)
-
-
-def _freeze_non_lora(params):
-    """stop_gradient on every non-LoRA leaf: grads for frozen leaves are
-    zero and DCE'd (no 2x-param grad buffers for PEFT archs)."""
-    def one(path, p):
-        from repro.sharding.partition import leaf_path_str
-        return p if "lora" in leaf_path_str(path) else jax.lax.stop_gradient(p)
-    return jax.tree_util.tree_map_with_path(one, params)
 
 
 def _train_state_specs(params_shape, ocfg, rules, mesh, *, zero1=True,
@@ -236,7 +227,7 @@ def _lm_train_cell(spec: ArchSpec, shape: ShapeSpec, mesh, cfg: ModelConfig,
 
     def loss_fn(params, batch):
         if lora:
-            params = _freeze_non_lora(params)
+            params = freeze_non_lora(params)
         out = forward(params, cfg, batch["tokens"],
                       positions=batch["positions"], is_sum=batch["is_sum"],
                       valid=batch["valid"], dti_enabled=True, window=win)

@@ -44,6 +44,7 @@ from repro.core.dti import (PromptStats, SpecialTokens, batch_prompts,
 from repro.core.losses import ctr_loss
 from repro.core.metrics import ctr_metrics
 from repro.data.synthetic import make_ctr_dataset, split_users
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import ModelConfig, forward, init_params
 from repro.obs.clock import monotonic
 from repro.serve.engine import make_prefill_fn
@@ -157,9 +158,12 @@ def run_lm(args) -> Dict:
         stats = pstats
 
     params = init_params(jax.random.PRNGKey(args.seed), cfg)
+    # the arch's PEFT recipe applies where the config carries adapters; a
+    # config without them (dti-llama's CPU REPRO) is a full fine-tune
     ocfg = OptimizerConfig(lr=args.lr, schedule="cosine",
                            warmup_steps=max(10, args.steps // 10),
-                           total_steps=args.steps)
+                           total_steps=args.steps,
+                           trainable=arch.trainable if cfg.lora_rank else None)
     loss_fn = make_lm_loss_fn(cfg, window)
     state = init_train_state(params, ocfg)
     step_fn = make_train_step(loss_fn, ocfg)
@@ -219,7 +223,7 @@ def run_other(args) -> Dict:
     return result
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dti-llama")
     ap.add_argument("--paradigm", default="dti",
@@ -242,7 +246,12 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--log-every", type=int, default=20)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     if get_arch(args.arch).family == "lm":
         run_lm(args)

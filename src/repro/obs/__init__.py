@@ -14,8 +14,8 @@ Three small, dependency-free pieces shared by serve / train / stream:
   snapshots merge associatively (the same discipline
   ``StreamingAUC`` / ``StreamingLogLoss`` follow), superseding the
   ad-hoc counter dicts in the scheduler, page pool and stream windows.
-- :mod:`repro.obs.profile` — optional ``jax.profiler`` trace /
-  annotation hooks that degrade to no-ops when the profiler is absent.
+- :mod:`repro.obs.profile` — ``jax.profiler`` trace / annotation hooks;
+  a trace that was asked for and cannot start raises.
 
 See ``docs/observability.md`` for the span model, naming scheme and the
 overhead contract (zero new device syncs on the serving hot path).

@@ -1,51 +1,32 @@
-"""Optional ``jax.profiler`` hooks; degrade to no-ops when unavailable.
+"""``jax.profiler`` hooks.
 
 Two entry points:
 
 - :func:`trace` — context manager around a whole run, writing a device
-  profile to a directory (``serve_bench --jax-profile DIR``).
+  profile to a directory (``serve_bench --jax-profile DIR``). A trace
+  that was asked for and cannot start raises: a run that was meant to be
+  profiled never silently comes back without its profile.
 - :func:`annotate` — a ``TraceAnnotation`` so host-side span names show
   up on the device timeline; the scheduler opens one around each
   dispatch when its tracer was built with ``jax_annotate=True``.
-
-Both swallow a missing/broken profiler (old jax, no backend support)
-rather than making observability a hard dependency: the host-side
-tracer keeps working regardless.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
-try:  # profiler availability depends on jax version/build
-    from jax import profiler as _jax_profiler
-except Exception:  # pragma: no cover - env without jax.profiler
-    _jax_profiler = None
-
-
-def available() -> bool:
-    return _jax_profiler is not None
+from jax import profiler as _jax_profiler
 
 
 @contextmanager
 def trace(log_dir):
-    """``jax.profiler.trace`` if available, else a no-op."""
-    if _jax_profiler is None or not log_dir:
+    """``jax.profiler.trace`` into ``log_dir``; a no-op when none is given."""
+    if not log_dir:
         yield
         return
-    try:
-        ctx = _jax_profiler.trace(str(log_dir))
-    except Exception:
-        yield
-        return
-    with ctx:
+    with _jax_profiler.trace(str(log_dir)):
         yield
 
 
 def annotate(name: str):
-    """``jax.profiler.TraceAnnotation(name)`` if available, else no-op."""
-    if _jax_profiler is None:
-        return nullcontext()
-    try:
-        return _jax_profiler.TraceAnnotation(name)
-    except Exception:
-        return nullcontext()
+    """``jax.profiler.TraceAnnotation(name)``."""
+    return _jax_profiler.TraceAnnotation(name)

@@ -186,7 +186,7 @@ def _decode_attend(scores_rope, scores_nope, alibi, d, mask, is_sum_q, v_agg):
 def _gqa_decode_layer(lp: Params, h, kv: Params, *, cfg: ModelConfig, slots,
                       pos_buf, positions, is_sum, window, kind,
                       seg_q=None, seg_buf=None, impl="dense",
-                      block_size=None, interpret=None,
+                      attend=decode_attention,
                       write_idx=None, read_idx=None):
     b, s, _ = h.shape
     hq, hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -225,13 +225,12 @@ def _gqa_decode_layer(lp: Params, h, kv: Params, *, cfg: ModelConfig, slots,
         # quantized-KV contract: hand the kernel the raw int8 codes plus
         # scale sidecars; dequant + read-time RoPE happen in VMEM, and the
         # NoPE stream is the same codes dequantized without rotation
-        out = decode_attention(
+        out = attend(
             q_rope, k_raw, v_raw, positions, pos_buf, window=window,
             is_sum_q=is_sum if nope else None,
             q_nope=q if nope else None, k_nope=None,
             alibi=alibi_slopes(hq) if nope else None,
             seg_q=seg_q, seg_k=seg_buf, scale=scale,
-            block_size=block_size, interpret=interpret,
             k_scale=_cache_view(kv["k_scale"], read_idx)[..., None],
             v_scale=_cache_view(kv["v_scale"], read_idx),
             rope_start=0, rope_theta=cfg.rope_theta).astype(h.dtype)
@@ -251,13 +250,12 @@ def _gqa_decode_layer(lp: Params, h, kv: Params, *, cfg: ModelConfig, slots,
         # page-index gather) via index maps, applies every mask term via
         # index arithmetic and keeps the softmax online — no (B,H,s,cap)
         # score/prob tensors, empty cache blocks skipped
-        out = decode_attention(
+        out = attend(
             q_rope, k_rope, v_raw, positions, pos_buf, window=window,
             is_sum_q=is_sum if nope else None,
             q_nope=q if nope else None, k_nope=k_raw if nope else None,
             alibi=alibi_slopes(hq) if nope else None,
-            seg_q=seg_q, seg_k=seg_buf, scale=scale,
-            block_size=block_size, interpret=interpret).astype(h.dtype)
+            seg_q=seg_q, seg_k=seg_buf, scale=scale).astype(h.dtype)
         h = h + dense(lp["attn"]["o"], out.reshape(b, s, hq * hd))
         h, aux = _ffn(lp, h, cfg, kind)
         return h, kv, aux
@@ -290,7 +288,7 @@ def _gqa_decode_layer(lp: Params, h, kv: Params, *, cfg: ModelConfig, slots,
 def _mla_decode_layer(lp: Params, h, kv: Params, *, cfg: ModelConfig,
                       slots, pos_buf, positions, is_sum, window, kind,
                       seg_q=None, seg_buf=None, impl="dense",
-                      block_size=None, interpret=None,
+                      attend=decode_attention,
                       write_idx=None, read_idx=None):
     """Absorbed-MLA decode: scores and values against the latent cache."""
     b, s, _ = h.shape
@@ -351,13 +349,12 @@ def _mla_decode_layer(lp: Params, h, kv: Params, *, cfg: ModelConfig,
                              axis=-1)[:, :, None, :]                # (B,cap,1,2)
             qn_eff = (jnp.concatenate([q_abs, q_pe], axis=-1)
                       if nope else None)
-            o_lat = decode_attention(
+            o_lat = attend(
                 q_eff, k_codes, ckv_v[:, :, None, :], positions, pos_buf,
                 window=window, is_sum_q=is_sum if nope else None,
                 q_nope=qn_eff, k_nope=None,
                 alibi=alibi_slopes(hq) if nope else None,
                 seg_q=seg_q, seg_k=seg_buf, scale=scale,
-                block_size=block_size, interpret=interpret,
                 k_scale=k_sc, v_scale=c_sv_view[:, :, None],
                 rope_start=cfg.kv_lora_rank, rope_theta=cfg.rope_theta)
             out = jnp.einsum("bshr,rhd->bshd", o_lat.astype(h.dtype), w_uv)
@@ -380,13 +377,12 @@ def _mla_decode_layer(lp: Params, h, kv: Params, *, cfg: ModelConfig,
         qn_eff = (jnp.concatenate([q_abs, q_pe], axis=-1) if nope else None)
         kn_eff = (jnp.concatenate([ckv_v, kpe_v], axis=-1)[:, :, None, :]
                   if nope else None)
-        o_lat = decode_attention(
+        o_lat = attend(
             q_eff, k_eff, ckv_v[:, :, None, :], positions, pos_buf,
             window=window, is_sum_q=is_sum if nope else None,
             q_nope=qn_eff, k_nope=kn_eff,
             alibi=alibi_slopes(hq) if nope else None,
-            seg_q=seg_q, seg_k=seg_buf, scale=scale,
-            block_size=block_size, interpret=interpret)
+            seg_q=seg_q, seg_k=seg_buf, scale=scale)
         out = jnp.einsum("bshr,rhd->bshd", o_lat.astype(h.dtype), w_uv)
         h = h + dense(ap["o"], out.reshape(b, s, hq * dv))
         h, aux = _ffn(lp, h, cfg, kind)
@@ -434,7 +430,8 @@ def make_decode_fn(cfg: ModelConfig, *, window: int, ring: bool,
                    yes_id: int = 3, no_id: int = 4,
                    attn_impl: Optional[str] = None,
                    block_size: Optional[int] = None,
-                   interpret: Optional[bool] = None) -> Callable:
+                   interpret: Optional[bool] = None,
+                   mesh=None) -> Callable:
     """(params, cache, tokens (B,s), positions (B,s), is_sum (B,s)[,
     valid (B,s), commit (B,), seg (B,s)]) -> (p_click (B, s), new_cache).
 
@@ -455,6 +452,8 @@ def make_decode_fn(cfg: ModelConfig, *, window: int, ring: bool,
     ``block_size``/``interpret`` tune the kernel path only (interpret
     auto-resolves off-TPU, see ``repro.kernels.default_interpret``;
     ``block_size=None`` defers to ``repro.kernels.autotune.decode_block``).
+    ``mesh`` (the scheduler's serving mesh) runs the kernel per shard under
+    ``shard_map``: Mosaic kernels cannot be partitioned by GSPMD.
 
     Quantized caches (``init_lm_cache(kv_dtype="int8")``) are detected from
     the cache structure: layers quantize KV on write (codes + scale
@@ -496,6 +495,8 @@ def make_decode_fn(cfg: ModelConfig, *, window: int, ring: bool,
     if attn_impl is None:
         attn_impl = "pallas" if cfg.attn_impl == "pallas" else "dense"
     assert attn_impl in ("dense", "pallas"), f"unknown decode attn_impl {attn_impl!r}"
+    attend = partial(decode_attention, block_size=block_size,
+                     interpret=interpret, mesh=mesh)
 
     def decode(params: Params, cache: Cache, tokens: jax.Array,
                positions: jax.Array, is_sum: jax.Array,
@@ -571,8 +572,7 @@ def make_decode_fn(cfg: ModelConfig, *, window: int, ring: bool,
                     lp, hc, layer_kv, cfg=cfg, slots=slots, pos_buf=pos_buf,
                     positions=positions, is_sum=is_sum, window=window,
                     kind=kind, seg_q=seg, seg_buf=seg_buf, impl=attn_impl,
-                    block_size=block_size, interpret=interpret,
-                    write_idx=write_idx, read_idx=read_idx)
+                    attend=attend, write_idx=write_idx, read_idx=read_idx)
                 full = tuple(jax.lax.dynamic_update_index_in_dim(
                     t, layer_kv[nm].astype(t.dtype), li, 0)
                     for nm, t in zip(kv_names, full))

@@ -415,7 +415,8 @@ class ServeScheduler:
         # never read
         self._decode = jax.jit(
             make_decode_fn(cfg, window=window, ring=False,
-                           yes_id=yes_id, no_id=no_id, attn_impl=attn_impl),
+                           yes_id=yes_id, no_id=no_id, attn_impl=attn_impl,
+                           mesh=mesh),
             donate_argnums=(1,))
         self._free = jax.jit(free_slots, donate_argnums=(0,))
         self._retain = jax.jit(retain_slots, donate_argnums=(0,))

@@ -60,14 +60,27 @@ def schedule_lr(cfg: OptimizerConfig, step: jax.Array) -> jax.Array:
     raise ValueError(cfg.schedule)
 
 
+LORA_LEAVES = ("lora_a", "lora_b")
+
+
+def _is_lora(path) -> bool:
+    """The adapter factors; ``lora_scale`` is a constant, not a weight."""
+    return bool(path) and getattr(path[-1], "key", None) in LORA_LEAVES
+
+
 def _trainable_mask(cfg: OptimizerConfig, params: Params) -> Params:
     if cfg.trainable is None:
         return jax.tree_util.tree_map(lambda _: True, params)
     assert cfg.trainable == "lora"
-    flat = jax.tree_util.tree_flatten_with_path(params)[0]
-    treedef = jax.tree_util.tree_structure(params)
-    vals = [any("lora" in str(k) for k in path) for path, _ in flat]
-    return jax.tree_util.tree_unflatten(treedef, vals)
+    return jax.tree_util.tree_map_with_path(lambda p, _: _is_lora(p), params)
+
+
+def freeze_non_lora(params: Params) -> Params:
+    """stop_gradient on every leaf but the LoRA factors: the frozen
+    leaves' grads are constant zeros that XLA drops, so a PEFT step holds
+    no grad buffers for the base weights."""
+    return jax.tree_util.tree_map_with_path(
+        lambda p, x: x if _is_lora(p) else jax.lax.stop_gradient(x), params)
 
 
 def _decay_mask(params: Params) -> Params:
@@ -106,6 +119,14 @@ def global_norm(tree: Params) -> jax.Array:
     return jnp.sqrt(sum(leaves))
 
 
+def trainable_grad_norm(cfg: OptimizerConfig, grads: Params) -> jax.Array:
+    """Global norm over the leaves ``cfg`` trains (frozen grads are zero
+    by construction and are never read)."""
+    mask = jax.tree_util.tree_leaves(_trainable_mask(cfg, grads))
+    return global_norm([g for g, m in zip(jax.tree_util.tree_leaves(grads),
+                                          mask) if m])
+
+
 def adamw_update(cfg: OptimizerConfig, grads: Params, state: OptState,
                  params: Params, *, shard_specs=None):
     """Returns (new_params, new_state, stats).
@@ -117,7 +138,7 @@ def adamw_update(cfg: OptimizerConfig, grads: Params, state: OptState,
     fp32 gather buffers on minicpm-2b/dp)."""
     mask = _trainable_mask(cfg, params)
     dmask = _decay_mask(params)
-    gnorm = global_norm(grads)
+    gnorm = trainable_grad_norm(cfg, grads)
     clip = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9)) \
         if cfg.grad_clip > 0 else 1.0
     step = state.step + 1
@@ -198,5 +219,6 @@ def ef_compress_grads(grads: Params, error: Params):
 
 
 __all__ = ["OptimizerConfig", "OptState", "init_opt_state", "adamw_update",
+           "freeze_non_lora", "trainable_grad_norm",
            "schedule_lr", "global_norm", "compress_int8", "decompress_int8",
            "ef_compress_grads"]

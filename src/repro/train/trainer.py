@@ -19,7 +19,8 @@ from repro.obs.clock import monotonic
 from repro.obs.trace import NULL_TRACER
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optimizer import (OptimizerConfig, OptState, adamw_update,
-                                   ef_compress_grads, init_opt_state)
+                                   ef_compress_grads, freeze_non_lora,
+                                   init_opt_state)
 from repro.train.resilience import StragglerMonitor
 
 
@@ -48,7 +49,15 @@ def init_train_state(params, opt_cfg: OptimizerConfig,
 def make_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
                     options: TrainOptions = TrainOptions(),
                     in_shardings=None, out_shardings=None, jit: bool = True):
-    """loss_fn(params, batch, rng) -> (loss, metrics-dict)."""
+    """loss_fn(params, batch, rng) -> (loss, metrics-dict).
+
+    With ``opt_cfg.trainable == "lora"`` the loss sees the base weights
+    through ``freeze_non_lora``, so only the adapter factors get grads."""
+    if opt_cfg.trainable == "lora":
+        base_loss = loss_fn
+
+        def loss_fn(params, batch, rng):
+            return base_loss(freeze_non_lora(params), batch, rng)
 
     def step(state: TrainState, batch, rng):
         if options.grad_accum > 1:

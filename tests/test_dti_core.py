@@ -182,10 +182,11 @@ class TestMetrics:
         if y.min() == y.max():
             return
         a1 = auc(y, s)
-        # power-of-two scale + shift: strictly monotone AND exact in floats
-        # (sigmoid-style transforms can collapse near-equal scores into
-        # ties, legitimately changing the tie-averaged AUC)
-        a2 = auc(y, 4.0 * s - 1.0)
+        # power-of-two scale: strictly monotone AND exact in floats (a shift
+        # or a sigmoid-style transform can round near-equal scores into
+        # ties, e.g. 4*0.01-1 == 4*0.010000000000000002-1, legitimately
+        # changing the tie-averaged AUC)
+        a2 = auc(y, 4.0 * s)
         assert abs(a1 - a2) < 1e-9
 
     def test_log_loss_known(self):

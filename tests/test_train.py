@@ -124,6 +124,51 @@ class TestTrainStep:
             losses.append(float(m["loss"]))
         assert losses[-1] < 0.05 * losses[0]
 
+    def test_lora_steps_touch_only_the_adapter_factors(self):
+        """trainable="lora": three steps move lora_a/lora_b and nothing
+        else — base weights, biases, norms and the constant lora_scale stay
+        bit-identical and carry only scalar optimizer state."""
+        import dataclasses
+        from repro.configs.qwen2_1_5b import SMOKE
+        from repro.launch.train import make_lm_loss_fn
+        from repro.models.transformer import init_params
+
+        cfg = dataclasses.replace(SMOKE, lora_rank=4, attn_impl="dense")
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        before = jax.tree_util.tree_map(np.asarray, params)
+        ocfg = OptimizerConfig(lr=1e-2, schedule="const", warmup_steps=1,
+                               trainable="lora")
+        r = np.random.default_rng(0)
+        b, s = 2, 32
+        batch = {"tokens": r.integers(8, cfg.vocab_size, (b, s)),
+                 "positions": np.tile(np.arange(s), (b, 1)),
+                 "is_sum": r.random((b, s)) < 0.2,
+                 "labels": r.integers(0, 2, (b, s)),
+                 "valid": np.ones((b, s), bool)}
+        state = init_train_state(params, ocfg)
+        step = make_train_step(make_lm_loss_fn(cfg, 16), ocfg)
+        for i in range(3):
+            state, m = step(state, batch, jax.random.PRNGKey(i))
+            assert float(m["grad_norm"]) > 0
+
+        flat = jax.tree_util.tree_flatten_with_path(state.params)[0]
+        mu = jax.tree_util.tree_leaves(state.opt.mu)
+        master = jax.tree_util.tree_leaves(state.opt.master)
+        old = jax.tree_util.tree_leaves(before)
+        n_lora = 0
+        for (path, new), m_, ma, o in zip(flat, mu, master, old):
+            if path[-1].key in ("lora_a", "lora_b"):
+                n_lora += 1
+                assert m_.shape == new.shape and ma.shape == new.shape
+            else:
+                np.testing.assert_array_equal(np.asarray(new), o,
+                                              err_msg=str(path))
+                assert m_.shape == () and ma.shape == ()
+        # a/b on q, k, v, o, gate, up, down (layers stacked on axis 0)
+        assert n_lora == 2 * 7 and n_lora < len(flat)
+        lora_b = [np.asarray(x) for p, x in flat if p[-1].key == "lora_b"]
+        assert any(np.abs(x).max() > 0 for x in lora_b)
+
 
 class TestCheckpoint:
     def test_roundtrip_atomic_keepk(self, tmp_path):
