@@ -406,7 +406,7 @@ def decode_attention_bshd(st: DecodeStatics, pos_q, pos_k, sum_q, seg_q,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=st.interpret,
+        interpret=st.interpret, name="decode_attn",
     )(pos_q, pos_k, sum_q, seg_q, seg_k, alibi, q, k, v, qn, kn, ks, vs,
       rinv)
     return jnp.swapaxes(out, 1, 2)

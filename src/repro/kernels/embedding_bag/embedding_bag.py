@@ -55,7 +55,7 @@ def embedding_bag_pallas(table: jax.Array,      # (V, D)
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        interpret=interpret, name="embedding_bag",
     )(ids.reshape(-1).astype(jnp.int32),
       weights.reshape(-1).astype(jnp.float32), table)
     return out

@@ -304,7 +304,7 @@ def windowed_attention_fwd_bhsd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=st.interpret,
+        interpret=st.interpret, name="winattn_fwd",
     )(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k, alibi, q, k, v,
       qn, kn, v0)
     return out, lse

@@ -286,7 +286,7 @@ def windowed_attention_bwd_bhsd(
         in_specs=in_specs(seq_q_idx, seq_k_idx, q_idx, kv_idx, kv_idx,
                           qn_map, kn_map, v0_map, row_q_idx),
         out_specs=dq_specs, out_shape=dq_outs, scratch_shapes=dq_scratch,
-        compiler_params=sem, interpret=st.interpret,
+        compiler_params=sem, interpret=st.interpret, name="winattn_dq",
     )(*operands)
     dq = res[0]
     dqn = res[1] if st.use_nope else jnp.zeros_like(qn)
@@ -338,7 +338,7 @@ def windowed_attention_bwd_bhsd(
                           b_kv_idx, b_qn_map, b_kn_map, b_v0_map,
                           b_row_idx),
         out_specs=dkv_specs, out_shape=dkv_outs, scratch_shapes=dkv_scratch,
-        compiler_params=sem, interpret=st.interpret,
+        compiler_params=sem, interpret=st.interpret, name="winattn_dkv",
     )(*operands)
     dk = _head_sum(res[0], hk).astype(k.dtype)
     dv = _head_sum(res[1], hk).astype(v.dtype)

@@ -97,8 +97,9 @@ def make_lm_loss_fn(cfg: ModelConfig, window: int):
                       valid=batch["valid"],
                       segment_ids=batch.get("segment_ids"),
                       dti_enabled=cfg.dti_sum_token, window=window)
-        loss, _ = ctr_loss(params, cfg, out["hidden"], batch["is_sum"],
-                           batch["labels"], yes_id=SP.yes, no_id=SP.no)
+        with jax.named_scope("lm.loss"):
+            loss, _ = ctr_loss(params, cfg, out["hidden"], batch["is_sum"],
+                               batch["labels"], yes_id=SP.yes, no_id=SP.no)
         return loss + out["aux_loss"], {}
     return loss_fn
 

@@ -52,7 +52,11 @@ def dense(p: Params, x: jax.Array) -> jax.Array:
     """Apply a (possibly LoRA-augmented) linear layer."""
     y = x @ p["w"]
     if "lora_a" in p:
-        y = y + (x @ p["lora_a"]) @ p["lora_b"] * p["lora_scale"]
+        # the scope holds the adapter term and not the sum: XLA fuses the
+        # sum into the projection's own matmul, whose op_name is its root's
+        with jax.named_scope("lora"):
+            d = (x @ p["lora_a"]) @ p["lora_b"] * p["lora_scale"]
+        y = y + d
     if "b" in p:
         y = y + p["b"]
     return y

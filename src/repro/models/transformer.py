@@ -160,38 +160,42 @@ def _stack(layers):
 def _layer_fwd(lp: Params, h: jax.Array, cfg: ModelConfig, kind: str, *,
                positions, window, impl, dti: Optional[DTIAttnOpts],
                valid, cache=None):
-    x = rmsnorm(lp["ln_attn"], h, cfg.norm_eps)
-    if cfg.attn_block_size is not None:
-        block_size = cfg.attn_block_size
-    else:
-        from repro.kernels.autotune import train_block
-        block_size = train_block(x.shape[1], cfg.hd)
-    if cfg.attn_type == "mla":
-        a, new_cache = mla_attention(
-            lp["attn"], x, n_heads=cfg.n_heads, qk_nope_dim=cfg.qk_nope_dim,
-            qk_rope_dim=cfg.qk_rope_dim, v_head_dim=cfg.v_head_dim,
-            positions=positions, window=window, rope_theta=cfg.rope_theta,
-            impl=impl, q_chunk=cfg.attn_q_chunk,
-            block_size=block_size, dti=dti, cache=cache,
-            valid=valid)
-    else:
-        a, new_cache = gqa_attention(
-            lp["attn"], x, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.hd, positions=positions, window=window,
-            rope_theta=cfg.rope_theta, impl=impl, q_chunk=cfg.attn_q_chunk,
-            block_size=block_size, dti=dti, cache=cache,
-            valid=valid)
+    with jax.named_scope("lm.attn"):
+        x = rmsnorm(lp["ln_attn"], h, cfg.norm_eps)
+        if cfg.attn_block_size is not None:
+            block_size = cfg.attn_block_size
+        else:
+            from repro.kernels.autotune import train_block
+            block_size = train_block(x.shape[1], cfg.hd)
+        if cfg.attn_type == "mla":
+            a, new_cache = mla_attention(
+                lp["attn"], x, n_heads=cfg.n_heads,
+                qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
+                v_head_dim=cfg.v_head_dim, positions=positions,
+                window=window, rope_theta=cfg.rope_theta, impl=impl,
+                q_chunk=cfg.attn_q_chunk, block_size=block_size, dti=dti,
+                cache=cache, valid=valid)
+        else:
+            a, new_cache = gqa_attention(
+                lp["attn"], x, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                head_dim=cfg.hd, positions=positions, window=window,
+                rope_theta=cfg.rope_theta, impl=impl, q_chunk=cfg.attn_q_chunk,
+                block_size=block_size, dti=dti, cache=cache,
+                valid=valid)
     h = h + a
-    x = rmsnorm(lp["ln_ffn"], h, cfg.norm_eps)
-    if kind == "moe":
-        f, aux = moe_ffn(lp["ffn"], x, n_experts=cfg.n_experts, top_k=cfg.top_k,
-                         capacity_factor=cfg.capacity_factor,
-                         norm_topk=cfg.norm_topk)
-    else:
-        f, aux = swiglu(lp["ffn"], x), jnp.zeros((), jnp.float32)
+    with jax.named_scope("lm.mlp"):
+        x = rmsnorm(lp["ln_ffn"], h, cfg.norm_eps)
+        if kind == "moe":
+            f, aux = moe_ffn(lp["ffn"], x, n_experts=cfg.n_experts,
+                             top_k=cfg.top_k,
+                             capacity_factor=cfg.capacity_factor,
+                             norm_topk=cfg.norm_topk)
+        else:
+            f, aux = swiglu(lp["ffn"], x), jnp.zeros((), jnp.float32)
     return h + f, aux, new_cache
 
 
+@partial(jax.named_call, name="lm.forward")
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
             positions: Optional[jax.Array] = None,
             is_sum: Optional[jax.Array] = None,

@@ -1,6 +1,6 @@
 """Unified observability layer: span tracing, mergeable metrics, profiling.
 
-Three small, dependency-free pieces shared by serve / train / stream:
+Small pieces shared by serve / train / stream:
 
 - :mod:`repro.obs.clock` — the single monotonic clock every duration in
   the repo is measured on (``time.time()`` is reserved for checkpoint
@@ -14,8 +14,12 @@ Three small, dependency-free pieces shared by serve / train / stream:
   snapshots merge associatively (the same discipline
   ``StreamingAUC`` / ``StreamingLogLoss`` follow), superseding the
   ad-hoc counter dicts in the scheduler, page pool and stream windows.
-- :mod:`repro.obs.profile` — ``jax.profiler`` trace / annotation hooks;
-  a trace that was asked for and cannot start raises.
+- :mod:`repro.obs.profile` — the ``jax.profiler`` trace hook; a trace
+  that was asked for and cannot start raises. A ``jax_annotate`` tracer
+  puts every span on the profiler's host line.
+- :mod:`repro.obs.compiles` — the compile watch: every XLA compile as a
+  ``jit.compile`` span and ``jit.compiles`` / ``jit.compile_s`` counters
+  of the trainer and the scheduler that watch it.
 
 See ``docs/observability.md`` for the span model, naming scheme and the
 overhead contract (zero new device syncs on the serving hot path).

@@ -1,14 +1,9 @@
-"""``jax.profiler`` hooks.
-
-Two entry points:
-
-- :func:`trace` — context manager around a whole run, writing a device
-  profile to a directory (``serve_bench --jax-profile DIR``). A trace
-  that was asked for and cannot start raises: a run that was meant to be
-  profiled never silently comes back without its profile.
-- :func:`annotate` — a ``TraceAnnotation`` so host-side span names show
-  up on the device timeline; the scheduler opens one around each
-  dispatch when its tracer was built with ``jax_annotate=True``.
+"""``jax.profiler`` hook: :func:`trace`, a context manager around a whole
+run that writes a device profile to a directory (``serve_bench
+--jax-profile DIR``). A trace that was asked for and cannot start raises:
+a run that was meant to be profiled never silently comes back without
+its profile. Host spans reach the profile through
+``SpanTracer(jax_annotate=True)``, which annotates every span.
 """
 from __future__ import annotations
 
@@ -26,7 +21,3 @@ def trace(log_dir):
     with _jax_profiler.trace(str(log_dir)):
         yield
 
-
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation(name)``."""
-    return _jax_profiler.TraceAnnotation(name)

@@ -40,21 +40,27 @@ _VALID_PH = ("X", "i", "C", "M")
 
 
 class _Span:
-    """Context manager for one "X" event; reusable args via ``set``."""
+    """Context manager for one "X" event; reusable args via ``set``.
 
-    __slots__ = ("_tr", "name", "args", "_t0")
+    Under a ``jax_annotate`` tracer the span also opens a
+    ``jax.profiler.TraceAnnotation`` of its own name and args, so it sits
+    on the profiler's host line, on the device trace's clock."""
+
+    __slots__ = ("_tr", "name", "args", "_t0", "_ann")
 
     def __init__(self, tr: "SpanTracer", name: str, args: Optional[Dict]):
         self._tr = tr
         self.name = name
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **kw) -> None:
         """Attach args discovered mid-span (e.g. the chosen jit bucket).
 
         Must be called before the ``with`` block exits — the event is
-        written at ``__exit__``.
+        written at ``__exit__``. The profiler annotation keeps the args
+        the span opened with.
         """
         if self.args is None:
             self.args = kw
@@ -62,12 +68,19 @@ class _Span:
             self.args.update(kw)
 
     def __enter__(self) -> "_Span":
+        annotation = self._tr._annotation
+        if annotation is not None:
+            self._ann = annotation(self.name, **(self.args or {}))
+            self._ann.__enter__()
         self._t0 = self._tr.clock()
         return self
 
     def __exit__(self, *exc) -> None:
         tr = self._tr
         t1 = tr.clock()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         tr._push({"name": self.name, "ph": "X",
                   "ts": (self._t0 - tr._epoch) * 1e6,
                   "dur": (t1 - self._t0) * 1e6,
@@ -128,9 +141,9 @@ class SpanTracer:
         (defaults to the repo monotonic clock).
     capacity: ring size in events; the oldest events are dropped (and
         counted in ``dropped``) when full.
-    jax_annotate: when True, instrumented dispatch sites additionally
-        open ``jax.profiler`` annotations (see ``repro.obs.profile``),
-        so device timelines carry the same names as host spans.
+    jax_annotate: when True, every span also opens a
+        ``jax.profiler.TraceAnnotation`` of its name and args, so a
+        profiler trace carries the host spans on its own clock.
     """
 
     enabled = True
@@ -140,6 +153,10 @@ class SpanTracer:
         self.clock = clock
         self.capacity = int(capacity)
         self.jax_annotate = bool(jax_annotate)
+        self._annotation = None
+        if self.jax_annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.pid = os.getpid()
         self._events: deque = deque(maxlen=self.capacity)
         self.dropped = 0
@@ -157,6 +174,18 @@ class SpanTracer:
     def instant(self, name: str, **args) -> None:
         self._push({"name": name, "ph": "i", "s": "t",
                     "ts": (self.clock() - self._epoch) * 1e6,
+                    "pid": self.pid, "tid": threading.get_ident(),
+                    **({"args": args} if args else {})})
+
+    def complete(self, name: str, dur_s: float, **args) -> None:
+        """A span of ``dur_s`` seconds that ends now, for work timed by
+        someone else (the compile watch's ``jit.compile``); a start
+        before the tracer's epoch is cut to it."""
+        t1 = self.clock()
+        t0 = max(t1 - dur_s, self._epoch)
+        self._push({"name": name, "ph": "X",
+                    "ts": (t0 - self._epoch) * 1e6,
+                    "dur": (t1 - t0) * 1e6,
                     "pid": self.pid, "tid": threading.get_ident(),
                     **({"args": args} if args else {})})
 

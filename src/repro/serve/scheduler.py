@@ -85,7 +85,7 @@ import numpy as np
 from repro.core.dti import SpecialTokens
 from repro.data.requests import RadixTree
 from repro.models.transformer import ModelConfig
-from repro.obs import profile as obs_profile
+from repro.obs import compiles
 from repro.obs.clock import monotonic
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER
@@ -371,6 +371,7 @@ class ServeScheduler:
         # read-only properties over these — same names, same values.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = MetricsRegistry()
+        compiles.watch(self)           # jit.compile spans, jit.compiles
         m = self.metrics
         self._c_steps = m.counter("serve.steps")
         self._c_shared_admissions = m.counter("serve.shared_admissions")
@@ -617,11 +618,14 @@ class ServeScheduler:
         traffic never hits a compile mid-request.
 
         Because this is the one place every jit bucket is entered cold
-        and off the hot path, it also measures per-bucket compile-vs-
-        execute time (first call = compile + execute, second = execute)
-        into the ``jit.*`` gauges — see ``jit_stats()``. The blocking
-        calls here are warmup-only; the serving hot path stays at its
-        single harvest sync."""
+        and off the hot path, it also records per bucket the first call,
+        the second (execute only) and the compiles the first call made,
+        as the compile watch measured them (``jit.compile_s``; a load
+        from the persistent compile cache counts as its load time) into
+        the ``jit.*`` gauges — see ``jit_stats()``. The blocking calls
+        here are warmup-only; the serving hot path stays at its single
+        harvest sync."""
+        compile_s = self.metrics.counter("jit.compile_s")
         for s in self.buckets:
             z = np.zeros((self.n_slots, s), np.int32)
             f = np.zeros((self.n_slots, s), bool)
@@ -629,19 +633,19 @@ class ServeScheduler:
                     jnp.asarray(f),
                     jnp.asarray(np.zeros((self.n_slots,), bool)),
                     jnp.asarray(np.full((self.n_slots, s), -1, np.int32)))
+            c0 = compile_s.value
             t0 = monotonic()
             p, self.cache = self._decode(self.params, self.cache, *args)
             jax.block_until_ready(p)
             t1 = monotonic()
+            c1 = compile_s.value
             p, self.cache = self._decode(self.params, self.cache, *args)
             jax.block_until_ready(p)
             t2 = monotonic()
-            first, execute = t1 - t0, t2 - t1
             pre = f"jit.bucket{int(s)}"
-            self.metrics.gauge(pre + ".first_s").set(first)
-            self.metrics.gauge(pre + ".execute_s").set(execute)
-            self.metrics.gauge(pre + ".compile_s").set(
-                max(0.0, first - execute))
+            self.metrics.gauge(pre + ".first_s").set(t1 - t0)
+            self.metrics.gauge(pre + ".execute_s").set(t2 - t1)
+            self.metrics.gauge(pre + ".compile_s").set(c1 - c0)
         # the row-op jits too (no-op masks/counts), so the first real
         # admission/eviction doesn't pay their compiles mid-run
         none = jnp.asarray(np.zeros((self.n_slots,), bool))
@@ -653,8 +657,9 @@ class ServeScheduler:
         jax.block_until_ready(self.cache["pos"])
 
     def jit_stats(self) -> Dict[int, Dict[str, float]]:
-        """Per-jit-bucket compile-vs-execute timing measured by
-        ``warmup()``: ``{bucket: {compile_s, execute_s, first_s}}``.
+        """Per-jit-bucket timing measured by ``warmup()``: ``{bucket:
+        {compile_s, execute_s, first_s}}``, ``compile_s`` being the
+        compile watch's seconds of compiles during the first call.
         Empty before warmup. Survives ``reset_stats`` (the gauges sit
         under the un-reset ``jit.`` prefix), so benchmarks that reset
         after warmup still report what the compiles cost."""
@@ -1633,9 +1638,7 @@ class ServeScheduler:
                 commit[row] = u.commit
 
         # async dispatch: p stays on device until this step is harvested
-        ann = (obs_profile.annotate(f"decode.b{int(s)}")
-               if tr.jax_annotate else _NULLCTX)
-        with tr.span("dispatch", bucket=int(s), rows=len(work)), ann:
+        with tr.span("dispatch", bucket=int(s), rows=len(work)):
             p, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(positions), jnp.asarray(is_sum),

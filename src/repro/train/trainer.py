@@ -15,7 +15,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import compiles
 from repro.obs.clock import monotonic
+from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optimizer import (OptimizerConfig, OptState, adamw_update,
@@ -60,12 +62,16 @@ def make_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
             return base_loss(freeze_non_lora(params), batch, rng)
 
     def step(state: TrainState, batch, rng):
+        # named scopes mark each op's phase in the compiled program's
+        # op_name metadata (train.grad: forward and backward; their
+        # transpose(...) half is the backward), for device traces
         if options.grad_accum > 1:
             def micro(carry, mb):
                 g_acc, l_acc, rng = carry
                 rng, sub = jax.random.split(rng)
-                (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                    state.params, mb, sub)
+                with jax.named_scope("train.grad"):
+                    (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                        state.params, mb, sub)
                 g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
                 return (g_acc, l_acc + loss, rng), None
 
@@ -82,15 +88,16 @@ def make_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
             loss = loss / n
             metrics = {}
         else:
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params, batch, rng)
+            with jax.named_scope("train.grad"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(state.params, batch, rng)
 
-        ef_error = state.ef_error
-        if options.compress_grads:
-            grads, ef_error = ef_compress_grads(grads, ef_error)
-
-        params, opt, stats = adamw_update(opt_cfg, grads, state.opt,
-                                          state.params)
+        with jax.named_scope("train.optimizer"):
+            ef_error = state.ef_error
+            if options.compress_grads:
+                grads, ef_error = ef_compress_grads(grads, ef_error)
+            params, opt, stats = adamw_update(opt_cfg, grads, state.opt,
+                                              state.params)
         metrics = dict(metrics or {})
         metrics.update(loss=loss, **stats)
         return TrainState(params, opt, ef_error), metrics
@@ -116,6 +123,17 @@ class Trainer:
     both, and ``launch.train`` derives steady tokens/s from the steady
     half only. Per-step ``sec`` entries in ``history`` are unchanged
     (the first record still carries its compile-inclusive duration).
+
+    Observability: each step is a ``train.step`` span (the rng split and
+    the call, ``train.dispatch``, then the wait for its loss,
+    ``train.wait``) with siblings ``train.feed`` (the next batch),
+    ``train.fetch`` (the metrics read to the host and recorded) and
+    ``train.checkpoint``, each carrying ``step``; no host work between
+    steps falls outside them. ``metrics`` counts ``train.steps`` and,
+    from batches of NumPy arrays (no device read), ``train.targets``
+    (``is_sum``), ``train.tokens`` (row tokens, pad included) and
+    ``train.pad_tokens`` (``~valid``); the compile watch adds
+    ``jit.compiles`` / ``jit.compile_s`` and ``jit.compile`` spans.
     """
     step_fn: Callable
     state: TrainState
@@ -130,6 +148,22 @@ class Trainer:
     compile_s: Optional[float] = None  # first executed step (compile+run)
     steady_s: float = 0.0              # sum of post-compile step times
     steady_steps: int = 0
+    metrics: MetricsRegistry = dataclasses.field(
+        default_factory=MetricsRegistry)
+
+    def __post_init__(self):
+        compiles.watch(self)
+
+    def _count(self, batch) -> None:
+        m = self.metrics
+        m.counter("train.steps").inc()
+        is_sum = batch.get("is_sum") if isinstance(batch, dict) else None
+        valid = batch.get("valid") if isinstance(batch, dict) else None
+        if isinstance(is_sum, np.ndarray):
+            m.counter("train.targets").inc(int(is_sum.sum()))
+        if isinstance(valid, np.ndarray):
+            m.counter("train.tokens").inc(int(valid.size))
+            m.counter("train.pad_tokens").inc(int(valid.size - valid.sum()))
 
     def timing(self) -> Dict[str, float]:
         """Compile-vs-steady split of this trainer's executed steps:
@@ -149,41 +183,49 @@ class Trainer:
 
     def run(self, batches: Iterator, *, n_steps: int, rng=None,
             host_time_fn: Optional[Callable[[int, float], Dict[int, float]]] = None):
-        rng = rng if rng is not None else jax.random.PRNGKey(0)
         tracer = self.tracer if self.tracer is not None else NULL_TRACER
         target = self.step + n_steps
-        for batch in batches:
-            if self.step >= target:
+        batches = iter(batches)
+        while self.step < target:
+            n = self.step + 1
+            with tracer.span("train.feed", step=n):
+                batch = next(batches, None)
+            if batch is None:
                 break
-            rng, sub = jax.random.split(rng)
             t0 = monotonic()
-            with tracer.span("train.step", step=self.step + 1):
-                self.state, metrics = self.step_fn(self.state, batch, sub)
-                jax.block_until_ready(metrics["loss"])
+            with tracer.span("train.step", step=n):
+                with tracer.span("train.dispatch", step=n):
+                    if rng is None:
+                        rng = jax.random.PRNGKey(0)
+                    rng, sub = jax.random.split(rng)
+                    self.state, metrics = self.step_fn(self.state, batch, sub)
+                with tracer.span("train.wait", step=n):
+                    jax.block_until_ready(metrics["loss"])
             dt = monotonic() - t0
             if self.compile_s is None:
                 self.compile_s = dt
             else:
                 self.steady_s += dt
                 self.steady_steps += 1
-            self.step += 1
-            rec = {k: float(v) for k, v in metrics.items()}
-            rec.update(step=self.step, sec=dt)
-            self.history.append(rec)
-            if self.monitor is not None:
-                times = (host_time_fn(self.step, dt) if host_time_fn
-                         else {0: dt})
-                report = self.monitor.update(self.step, times)
-                if report.stragglers:
-                    self.log_fn(f"[straggler] step {self.step}: "
-                                f"hosts {report.stragglers} "
-                                f"worst/median={report.worst_ratio:.2f}")
+            self.step = n
+            with tracer.span("train.fetch", step=n):
+                self._count(batch)
+                rec = {k: float(v) for k, v in metrics.items()}
+                rec.update(step=n, sec=dt)
+                self.history.append(rec)
+                if self.monitor is not None:
+                    times = host_time_fn(n, dt) if host_time_fn else {0: dt}
+                    report = self.monitor.update(n, times)
+                    if report.stragglers:
+                        self.log_fn(f"[straggler] step {n}: "
+                                    f"hosts {report.stragglers} "
+                                    f"worst/median={report.worst_ratio:.2f}")
+                if n % self.log_every == 0:
+                    self.log_fn(f"[step {n}] loss={rec['loss']:.4f} "
+                                f"lr={rec.get('lr', 0):.2e} {dt*1e3:.0f}ms")
             if self.ckpt is not None:
-                self.ckpt.maybe_save(self.step, self.state,
-                                     meta={"step": self.step})
-            if self.step % self.log_every == 0:
-                self.log_fn(f"[step {self.step}] loss={rec['loss']:.4f} "
-                            f"lr={rec.get('lr', 0):.2e} {dt*1e3:.0f}ms")
+                with tracer.span("train.checkpoint", step=n):
+                    self.ckpt.maybe_save(n, self.state, meta={"step": n})
         if self.ckpt is not None:
             self.ckpt.save(self.step, self.state, meta={"step": self.step},
                            block=True)

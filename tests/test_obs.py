@@ -431,6 +431,190 @@ def test_trainer_compile_vs_steady_split():
     assert [e["args"]["step"] for e in spans] == [1, 2, 3, 4]
 
 
+class _Loss:
+    """A step's loss whose wait and read advance a manual clock."""
+
+    def __init__(self, clk, wait_s, value=0.5):
+        self.clk, self.wait_s, self.value = clk, wait_s, value
+
+    def block_until_ready(self):
+        self.clk.advance(self.wait_s)
+        return self
+
+    def __float__(self):
+        self.clk.advance(0.001)
+        return self.value
+
+
+def _batches(n, rows=2, width=8):
+    rng = np.random.default_rng(1)
+    out = []
+    for _ in range(n):
+        valid = rng.random((rows, width)) < 0.7
+        out.append({"is_sum": valid & (rng.random((rows, width)) < 0.3),
+                    "valid": valid})
+    return out
+
+
+def test_trainer_host_spans_and_counters():
+    """Each step: feed beside train.step, dispatch and wait inside it,
+    fetch after it, with exact times; the counters equal the batches'
+    targets, row tokens and pad tokens."""
+    clk = ManualClock()
+    tr = SpanTracer(clock=clk)
+    state = init_train_state({"w": np.zeros(2, np.float32)},
+                             OptimizerConfig(lr=1e-3))
+
+    def step_fn(state, batch, rng):
+        clk.advance(0.010)                   # dispatch
+        return state, {"loss": _Loss(clk, 0.200)}
+
+    def feed(batches):
+        for b in batches:
+            clk.advance(0.003)
+            yield b
+
+    bs = _batches(3)
+    trainer = Trainer(step_fn, state, log_every=100, tracer=tr)
+    trainer.run(feed(bs), n_steps=3)
+    ev = {(e["name"], e["args"]["step"]): e for e in tr.events()
+          if e["name"].startswith("train.")}
+    assert {n for n, _ in ev} == {"train.feed", "train.step",
+                                  "train.dispatch", "train.wait",
+                                  "train.fetch"}
+
+    def at(name, step):
+        e = ev[(name, step)]
+        return e["ts"] / 1e6, (e["ts"] + e["dur"]) / 1e6
+
+    t = 0.0
+    for n in (1, 2, 3):
+        assert at("train.feed", n) == pytest.approx((t, t + 0.003))
+        a = t + 0.003
+        assert at("train.step", n) == pytest.approx((a, a + 0.210))
+        assert at("train.dispatch", n)[0] == pytest.approx(a)
+        assert at("train.dispatch", n)[1] - a == pytest.approx(0.010, abs=1e-3)
+        assert at("train.wait", n) == pytest.approx((a + 0.010, a + 0.210))
+        assert at("train.fetch", n) == pytest.approx((a + 0.210, a + 0.211))
+        t = a + 0.211
+    snap = trainer.metrics.snapshot("train.")
+    assert snap["train.steps"]["value"] == 3
+    assert snap["train.targets"]["value"] == sum(int(b["is_sum"].sum())
+                                                 for b in bs)
+    assert snap["train.tokens"]["value"] == sum(b["valid"].size for b in bs)
+    assert snap["train.pad_tokens"]["value"] == sum(int((~b["valid"]).sum())
+                                                    for b in bs)
+
+
+def _count_train_syncs(monkeypatch, tracer):
+    """Device syncs of a trainer run: jax.block_until_ready calls,
+    np.asarray and float() of device arrays."""
+    import jax.numpy as jnp
+    from jax._src.array import ArrayImpl
+    counts = {"asarray": 0, "block": 0, "float": 0}
+    real_asarray, real_block = np.asarray, jax.block_until_ready
+    real_float = ArrayImpl.__float__
+
+    def counting_asarray(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            counts["asarray"] += 1
+        return real_asarray(a, *args, **kw)
+
+    def counting_block(x):
+        counts["block"] += 1
+        return real_block(x)
+
+    def counting_float(self):
+        counts["float"] += 1
+        return real_float(self)
+
+    @jax.jit
+    def step_fn(state, batch, rng):
+        loss = jnp.sum(state.params["w"] ** 2)
+        return state, {"loss": loss, "grad_norm": loss * 2}
+
+    state = init_train_state({"w": np.ones(2, np.float32)},
+                             OptimizerConfig(lr=1e-3))
+    step_fn(state, {}, jax.random.PRNGKey(0))        # compile outside
+    monkeypatch.setattr(np, "asarray", counting_asarray)
+    monkeypatch.setattr(jax, "block_until_ready", counting_block)
+    monkeypatch.setattr(ArrayImpl, "__float__", counting_float)
+    try:
+        trainer = Trainer(step_fn, state, log_every=100, tracer=tracer)
+        trainer.run(iter([{}] * 3), n_steps=3)
+    finally:
+        monkeypatch.undo()
+    return counts
+
+
+def test_trainer_spans_add_zero_device_syncs(monkeypatch):
+    """One wait and one read per metric a step, traced or not (the
+    counters read only host arrays)."""
+    base = _count_train_syncs(monkeypatch, tracer=None)
+    traced = _count_train_syncs(monkeypatch, tracer=SpanTracer())
+    assert base == traced == {"asarray": 0, "block": 3, "float": 6}
+
+
+def test_compile_watch_names_a_recompile():
+    """A new shape recompiles: one ``jit.compile`` span in the tracer and
+    ``jit.compiles`` + 1 in the owner's registry."""
+    tr = SpanTracer()
+    state = init_train_state({"w": np.zeros(2, np.float32)},
+                             OptimizerConfig(lr=1e-3))
+    trainer = Trainer(lambda s, b, r: (s, {"loss": np.float32(0)}), state,
+                      tracer=tr)
+    f = jax.jit(lambda x: x * 3 + 1)
+    f(np.ones((3, 5), np.float32)).block_until_ready()
+    n0 = trainer.metrics.counter("jit.compiles").value
+    s0 = trainer.metrics.counter("jit.compile_s").value
+    tr.clear()
+    f(np.ones((7, 11), np.float32)).block_until_ready()
+    spans = [e for e in tr.events() if e["name"] == "jit.compile"]
+    assert len(spans) == 1 and spans[0]["dur"] > 0
+    assert trainer.metrics.counter("jit.compiles").value == n0 + 1
+    assert trainer.metrics.counter("jit.compile_s").value > s0
+    f(np.ones((7, 11), np.float32)).block_until_ready()     # cached
+    assert trainer.metrics.counter("jit.compiles").value == n0 + 1
+
+
+def test_warmup_compile_s_is_the_compile_watch_measure():
+    """``jit_stats()`` ``compile_s`` is the compile watch's seconds of the
+    bucket's first call (every bucket compiles cold here), and their sum
+    is the registry's ``jit.compile_s`` over warmup's decode calls."""
+    sched, _, _ = _drained_sched(buckets=(8, 16))
+    total0 = sched.metrics.counter("jit.compile_s").value
+    n0 = sched.metrics.counter("jit.compiles").value
+    sched.warmup()
+    st = sched.jit_stats()
+    assert set(st) == {8, 16}
+    for s in st.values():
+        assert set(s) == {"compile_s", "execute_s", "first_s"}
+        assert 0 < s["compile_s"] <= s["first_s"]
+    assert sched.metrics.counter("jit.compiles").value >= n0 + 2
+    assert sum(s["compile_s"] for s in st.values()) <= (
+        sched.metrics.counter("jit.compile_s").value - total0)
+
+
+def test_annotated_spans_reach_the_profiler(tmp_path):
+    """``jax_annotate=True``: every span is a TraceAnnotation, so a
+    profiler trace holds the trainer's span names on its host plane."""
+    import glob
+    from jax.profiler import ProfileData
+    tr = SpanTracer(jax_annotate=True)
+    state = init_train_state({"w": np.zeros(2, np.float32)},
+                             OptimizerConfig(lr=1e-3))
+    trainer = Trainer(lambda s, b, r: (s, {"loss": np.float32(0.5)}), state,
+                      log_every=100, tracer=tr)
+    with jax.profiler.trace(str(tmp_path)):
+        trainer.run(iter(_batches(2)), n_steps=2)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for p in ProfileData.from_file(path).planes
+             if p.name.startswith("/host:") for ln in p.lines
+             for e in ln.events}
+    assert {"train.feed", "train.step", "train.dispatch", "train.wait",
+            "train.fetch"} <= names
+
+
 # ---------------------------------------------------------------------------
 # obs_report CLI
 # ---------------------------------------------------------------------------
