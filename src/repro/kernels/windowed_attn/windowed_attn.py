@@ -71,6 +71,14 @@ def n_kv_blocks(window: int, blk: int, n_q: int) -> int:
     return min(max(n_kv, 1), n_q)
 
 
+def band_steps(window: int, blk: int, n_q: int) -> Tuple[int, int]:
+    """-> (grid steps, live steps) of one (row, head) in each kernel call.
+    A step is live when its kv block lies inside the band: q block ``i``
+    has ``min(i + 1, n_kv)`` of them (the dk/dv walk has as many)."""
+    n_kv = n_kv_blocks(window, blk, n_q)
+    return n_q * n_kv, sum(min(i + 1, n_kv) for i in range(n_q))
+
+
 def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
             seg_q_ref, seg_k_ref,
             alibi_ref,
@@ -80,8 +88,7 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
             *, blk: int, n_kv: int, window: int, scale: float,
             sum_isolated: bool, use_seg: bool, use_nope: bool,
             use_reset: bool, y_min: float, y_max: float, midpoint: float):
-    ikv = pl.program_id(3)
-    iq = pl.program_id(2)
+    ih, iq, ikv = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ikv == 0)
     def _init():
@@ -89,58 +96,58 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                   # (blk, D)
-    k = k_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    # a kv block before the sequence start adds nothing: skip the body
+    @pl.when(iq - (n_kv - 1) + ikv >= 0)
+    def _step():
+        q = q_ref[0, 0].astype(jnp.float32)               # (blk, D)
+        k = k_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
 
-    # per-row operands arrive as (1, blk) rows; query-side ones are
-    # transposed to (blk, 1) columns so every mask term broadcasts 2-D
-    pos_q = pos_q_ref[0].T                                # (blk, 1) int32
-    pos_k = pos_k_ref[0]                                  # (1, blk)
-    d = pos_q - pos_k                                     # (blk, blk)
-    sum_q = sum_q_ref[0].T != 0                           # (blk, 1)
+        # per-row operands arrive as (1, blk) rows; query-side ones are
+        # transposed to (blk, 1) columns so every mask term broadcasts 2-D
+        pos_q = pos_q_ref[0].T                            # (blk, 1) int32
+        pos_k = pos_k_ref[0]                              # (1, blk)
+        d = pos_q - pos_k                                 # (blk, blk)
+        sum_q = sum_q_ref[0].T != 0                       # (blk, 1)
 
-    if use_nope:
-        qn = qn_ref[0, 0].astype(jnp.float32)
-        kn = kn_ref[0, 0].astype(jnp.float32)
-        sn = jax.lax.dot_general(qn, kn, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-        sn = sn - alibi_ref[pl.program_id(1)] * d.astype(jnp.float32)
-        s = jnp.where(sum_q, sn, s)
+        if use_nope:
+            qn = qn_ref[0, 0].astype(jnp.float32)
+            kn = kn_ref[0, 0].astype(jnp.float32)
+            sn = jax.lax.dot_general(
+                qn, kn, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            sn = sn - alibi_ref[ih] * d.astype(jnp.float32)
+            s = jnp.where(sum_q, sn, s)
 
-    # mask: causal + window + key-padding (+ SUM isolation) (+ same packed
-    # segment) + real kv block
-    mask = (d >= 0) & (d <= window) & (valid_k_ref[0] != 0)
-    if sum_isolated:
-        mask &= (sum_k_ref[0] == 0) | (d == 0)
-    if use_seg:
-        mask &= seg_q_ref[0].T == seg_k_ref[0]
-    j_actual = iq - (n_kv - 1) + ikv
-    mask &= j_actual >= 0                                  # clamped block
-    s = jnp.where(mask, s, NEG_INF)
+        # mask: causal + window + key-padding (+ SUM isolation) (+ segment)
+        mask = (d >= 0) & (d <= window) & (valid_k_ref[0] != 0)
+        if sum_isolated:
+            mask &= (sum_k_ref[0] == 0) | (d == 0)
+        if use_seg:
+            mask &= seg_q_ref[0].T == seg_k_ref[0]
+        s = jnp.where(mask, s, NEG_INF)
 
-    # online softmax
-    m_prev = m_ref[...]                                    # (blk, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    w = jnp.exp(s - m_new)
-    w = jnp.where(mask, w, 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1, keepdims=True)
-    m_ref[...] = m_new
+        # online softmax
+        m_prev = m_ref[...]                               # (blk, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        w = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1, keepdims=True)
+        m_ref[...] = m_new
 
-    v = v_ref[0, 0].astype(jnp.float32)
-    acc = acc_ref[...] * alpha
-    acc += jax.lax.dot_general(w, v, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    if use_reset:
-        a = y_min + (y_max - y_min) * jax.nn.sigmoid(
-            d.astype(jnp.float32) - midpoint)
-        wr = w * a * sum_q.astype(jnp.float32)
-        dv = v0_ref[0, 0].astype(jnp.float32) - v
-        acc += jax.lax.dot_general(wr, dv, (((1,), (0,)), ((), ())),
+        v = v_ref[0, 0].astype(jnp.float32)
+        acc = acc_ref[...] * alpha
+        acc += jax.lax.dot_general(w, v, (((1,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32)
-    acc_ref[...] = acc
+        if use_reset:
+            a = y_min + (y_max - y_min) * jax.nn.sigmoid(
+                d.astype(jnp.float32) - midpoint)
+            wr = w * a * sum_q.astype(jnp.float32)
+            dv = v0_ref[0, 0].astype(jnp.float32) - v
+            acc += jax.lax.dot_general(wr, dv, (((1,), (0,)), ((), ())),
+                                       preferred_element_type=jnp.float32)
+        acc_ref[...] = acc
 
     @pl.when(ikv == n_kv - 1)
     def _finish():
@@ -310,5 +317,5 @@ def windowed_attention_fwd_bhsd(
     return out, lse
 
 
-__all__ = ["AttnStatics", "choose_block", "n_kv_blocks", "prepare_inputs",
-           "windowed_attention_fwd_bhsd"]
+__all__ = ["AttnStatics", "band_steps", "choose_block", "n_kv_blocks",
+           "prepare_inputs", "windowed_attention_fwd_bhsd"]

@@ -50,7 +50,7 @@ def _dot(a, b, dims):
 
 
 def _recompute_tile(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
-                    alibi, q, k, qn, kn, v, v0, do, lse, delta, band_ok,
+                    alibi, q, k, qn, kn, v, v0, do, lse, delta,
                     *, window, scale, sum_isolated, use_seg, use_nope,
                     use_reset, y_min, y_max, midpoint):
     """Shared (q-block, kv-block) tile math for both backward passes.
@@ -74,7 +74,6 @@ def _recompute_tile(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
         mask &= (sum_k == 0) | (d == 0)
     if use_seg:
         mask &= seg_q == seg_k
-    mask &= band_ok
 
     # p == softmax probs exactly: lse = m + log(l) (or +1e30 on empty rows,
     # in which case every exp underflows to 0 and so does delta)
@@ -96,14 +95,14 @@ def _recompute_tile(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
     return p, ds_rope, ds_nope, asig
 
 
-def _load_tile(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
+def _load_tile(head, pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
                seg_q_ref, seg_k_ref, alibi_ref, q_ref, k_ref, v_ref,
                qn_ref, kn_ref, v0_ref, do_ref, lse_ref, delta_ref):
     # query-side rows transpose to (blk, 1) columns, key-side stay (1, blk)
     return dict(
         pos_q=pos_q_ref[0].T, pos_k=pos_k_ref[0], sum_q=sum_q_ref[0].T,
         sum_k=sum_k_ref[0], valid_k=valid_k_ref[0], seg_q=seg_q_ref[0].T,
-        seg_k=seg_k_ref[0], alibi=alibi_ref[pl.program_id(1)],
+        seg_k=seg_k_ref[0], alibi=alibi_ref[head],
         q=q_ref[0, 0].astype(_f32), k=k_ref[0, 0].astype(_f32),
         qn=qn_ref[0, 0].astype(_f32), kn=kn_ref[0, 0].astype(_f32),
         v=v_ref[0, 0].astype(_f32), v0=v0_ref[0, 0].astype(_f32),
@@ -117,8 +116,7 @@ def _dq_kernel(*refs, n_kv: int, use_nope: bool, scale: float, math_kw):
         dq_ref, dqn_ref, dq_acc, dqn_acc = refs
     else:
         (dq_ref, dq_acc), dqn_ref, dqn_acc = refs, None, None
-    ikv = pl.program_id(3)
-    iq = pl.program_id(2)
+    ih, iq, ikv = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ikv == 0)
     def _init():
@@ -126,16 +124,13 @@ def _dq_kernel(*refs, n_kv: int, use_nope: bool, scale: float, math_kw):
         if use_nope:
             dqn_acc[...] = jnp.zeros_like(dqn_acc)
 
-    t = _load_tile(*ins)
-    band_ok = (iq - (n_kv - 1) + ikv) >= 0                # clamped kv block
-    _, ds_rope, ds_nope, _ = _recompute_tile(
-        t["pos_q"], t["pos_k"], t["sum_q"], t["sum_k"], t["valid_k"],
-        t["seg_q"], t["seg_k"], t["alibi"], t["q"], t["k"], t["qn"],
-        t["kn"], t["v"], t["v0"], t["do"], t["lse"], t["delta"], band_ok,
-        **math_kw)
-    dq_acc[...] += scale * _dot(ds_rope, t["k"], ((1,), (0,)))
-    if use_nope:
-        dqn_acc[...] += scale * _dot(ds_nope, t["kn"], ((1,), (0,)))
+    @pl.when(iq - (n_kv - 1) + ikv >= 0)      # kv block inside the band
+    def _step():
+        t = _load_tile(ih, *ins)
+        _, ds_rope, ds_nope, _ = _recompute_tile(**t, **math_kw)
+        dq_acc[...] += scale * _dot(ds_rope, t["k"], ((1,), (0,)))
+        if use_nope:
+            dqn_acc[...] += scale * _dot(ds_nope, t["kn"], ((1,), (0,)))
 
     @pl.when(ikv == n_kv - 1)
     def _finish():
@@ -155,28 +150,24 @@ def _dkv_kernel(*refs, n_kv: int, n_q: int, use_nope: bool,
     dkn_acc = accs[2] if use_nope else None
     dv0_ref = outs[2 + int(use_nope)] if use_reset else None
     dv0_acc = accs[2 + int(use_nope)] if use_reset else None
-    ib = pl.program_id(3)                                  # band position
-    j = pl.program_id(2)                                   # kv block
+    ih, j, ib = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ib == 0)
     def _init():
         for acc in accs:
             acc[...] = jnp.zeros_like(acc)
 
-    t = _load_tile(*ins)
-    band_ok = (j + ib) <= (n_q - 1)                        # clamped q block
-    p, ds_rope, ds_nope, asig = _recompute_tile(
-        t["pos_q"], t["pos_k"], t["sum_q"], t["sum_k"], t["valid_k"],
-        t["seg_q"], t["seg_k"], t["alibi"], t["q"], t["k"], t["qn"],
-        t["kn"], t["v"], t["v0"], t["do"], t["lse"], t["delta"], band_ok,
-        **math_kw)
-    pv = p if not use_reset else p * (1.0 - asig)
-    dv_acc[...] += _dot(pv, t["do"], ((0,), (0,)))
-    if use_reset:
-        dv0_acc[...] += _dot(p * asig, t["do"], ((0,), (0,)))
-    dk_acc[...] += scale * _dot(ds_rope, t["q"], ((0,), (0,)))
-    if use_nope:
-        dkn_acc[...] += scale * _dot(ds_nope, t["qn"], ((0,), (0,)))
+    @pl.when(j + ib <= n_q - 1)               # q block inside the sequence
+    def _step():
+        t = _load_tile(ih, *ins)
+        p, ds_rope, ds_nope, asig = _recompute_tile(**t, **math_kw)
+        pv = p if not use_reset else p * (1.0 - asig)
+        dv_acc[...] += _dot(pv, t["do"], ((0,), (0,)))
+        if use_reset:
+            dv0_acc[...] += _dot(p * asig, t["do"], ((0,), (0,)))
+        dk_acc[...] += scale * _dot(ds_rope, t["q"], ((0,), (0,)))
+        if use_nope:
+            dkn_acc[...] += scale * _dot(ds_nope, t["qn"], ((0,), (0,)))
 
     @pl.when(ib == n_kv - 1)
     def _finish():

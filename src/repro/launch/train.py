@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import os
 from typing import Dict, List
 
@@ -45,7 +46,8 @@ from repro.core.losses import ctr_loss
 from repro.core.metrics import ctr_metrics
 from repro.data.synthetic import make_ctr_dataset, split_users
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models.transformer import ModelConfig, forward, init_params
+from repro.models.transformer import (ModelConfig, attn_band, forward,
+                                      init_params)
 from repro.obs.clock import monotonic
 from repro.serve.engine import make_prefill_fn
 from repro.train.checkpoint import CheckpointManager
@@ -101,6 +103,10 @@ def make_lm_loss_fn(cfg: ModelConfig, window: int):
             loss, _ = ctr_loss(params, cfg, out["hidden"], batch["is_sum"],
                                batch["labels"], yes_id=SP.yes, no_id=SP.no)
         return loss + out["aux_loss"], {}
+    if cfg.attn_impl == "pallas":
+        # the windowed kernels' band at a row length, for the trainer's
+        # winattn.* gauges (make_train_step carries it to the step)
+        loss_fn.attn_band = functools.partial(attn_band, cfg, window)
     return loss_fn
 
 

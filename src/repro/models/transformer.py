@@ -157,16 +157,29 @@ def _stack(layers):
 # forward
 # ---------------------------------------------------------------------------
 
+def _attn_block(cfg: ModelConfig, seq: int) -> int:
+    """Block size of the Pallas windowed attention at row length ``seq``."""
+    if cfg.attn_block_size is not None:
+        return cfg.attn_block_size
+    from repro.kernels.autotune import train_block
+    return train_block(seq, cfg.hd)
+
+
+def attn_band(cfg: ModelConfig, window: int, seq: int) -> Tuple[int, int]:
+    """-> (grid steps, live steps) of one (row, head) in each windowed-kernel
+    call at row length ``seq`` (``band_steps`` at the forward's block)."""
+    from repro.kernels.windowed_attn.windowed_attn import (band_steps,
+                                                           choose_block)
+    blk, s_pad = choose_block(seq, _attn_block(cfg, seq))
+    return band_steps(window, blk, s_pad // blk)
+
+
 def _layer_fwd(lp: Params, h: jax.Array, cfg: ModelConfig, kind: str, *,
                positions, window, impl, dti: Optional[DTIAttnOpts],
                valid, cache=None):
     with jax.named_scope("lm.attn"):
         x = rmsnorm(lp["ln_attn"], h, cfg.norm_eps)
-        if cfg.attn_block_size is not None:
-            block_size = cfg.attn_block_size
-        else:
-            from repro.kernels.autotune import train_block
-            block_size = train_block(x.shape[1], cfg.hd)
+        block_size = _attn_block(cfg, x.shape[1])
         if cfg.attn_type == "mla":
             a, new_cache = mla_attention(
                 lp["attn"], x, n_heads=cfg.n_heads,
@@ -312,4 +325,5 @@ def count_params(params: Params) -> int:
                if hasattr(x, "size"))
 
 
-__all__ = ["ModelConfig", "init_params", "forward", "lm_logits", "count_params"]
+__all__ = ["ModelConfig", "init_params", "forward", "lm_logits", "count_params",
+           "attn_band"]

@@ -54,7 +54,10 @@ def make_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
     """loss_fn(params, batch, rng) -> (loss, metrics-dict).
 
     With ``opt_cfg.trainable == "lora"`` the loss sees the base weights
-    through ``freeze_non_lora``, so only the adapter factors get grads."""
+    through ``freeze_non_lora``, so only the adapter factors get grads.
+    The returned step carries ``loss_fn.attn_band`` (or None) as its own
+    ``attn_band``, which ``Trainer`` reads for its ``winattn.*`` gauges."""
+    attn_band = getattr(loss_fn, "attn_band", None)
     if opt_cfg.trainable == "lora":
         base_loss = loss_fn
 
@@ -102,14 +105,16 @@ def make_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
         metrics.update(loss=loss, **stats)
         return TrainState(params, opt, ef_error), metrics
 
-    if not jit:
-        return step
-    kw = {}
-    if in_shardings is not None:
-        kw["in_shardings"] = in_shardings
-    if out_shardings is not None:
-        kw["out_shardings"] = out_shardings
-    return jax.jit(step, donate_argnums=(0,) if options.donate else (), **kw)
+    if jit:
+        kw = {}
+        if in_shardings is not None:
+            kw["in_shardings"] = in_shardings
+        if out_shardings is not None:
+            kw["out_shardings"] = out_shardings
+        step = jax.jit(step, donate_argnums=(0,) if options.donate else (),
+                       **kw)
+    step.attn_band = attn_band
+    return step
 
 
 @dataclasses.dataclass
@@ -133,7 +138,11 @@ class Trainer:
     from batches of NumPy arrays (no device read), ``train.targets``
     (``is_sum``), ``train.tokens`` (row tokens, pad included) and
     ``train.pad_tokens`` (``~valid``); the compile watch adds
-    ``jit.compiles`` / ``jit.compile_s`` and ``jit.compile`` spans.
+    ``jit.compiles`` / ``jit.compile_s`` and ``jit.compile`` spans. A step
+    with an ``attn_band`` (a Pallas-attention LM's, ``make_train_step``)
+    sets gauges ``winattn.grid_steps`` / ``winattn.live_steps`` once, for
+    the first batch's row length: the windowed kernels' grid steps and
+    the steps that run their body, per (row, head) and call.
     """
     step_fn: Callable
     state: TrainState
@@ -164,6 +173,13 @@ class Trainer:
         if isinstance(valid, np.ndarray):
             m.counter("train.tokens").inc(int(valid.size))
             m.counter("train.pad_tokens").inc(int(valid.size - valid.sum()))
+        band = getattr(self.step_fn, "attn_band", None)
+        tokens = batch.get("tokens") if isinstance(batch, dict) else None
+        if band is not None and tokens is not None \
+                and not m.names("winattn."):
+            grid, live = band(tokens.shape[1])
+            m.gauge("winattn.grid_steps").set(grid)
+            m.gauge("winattn.live_steps").set(live)
 
     def timing(self) -> Dict[str, float]:
         """Compile-vs-steady split of this trainer's executed steps:

@@ -27,9 +27,12 @@ from repro.core.dti import build_streaming_prompts, pack_prompts
 from repro.core.windowed import (ResetConfig, attention_blocked,
                                  attention_dense)
 from repro.kernels.windowed_attn.ops import windowed_attention
+from repro.kernels.windowed_attn.windowed_attn import band_steps
 from repro.launch.train import make_lm_loss_fn
 from repro.models.layers import alibi_slopes
 from repro.models.transformer import ModelConfig, init_params
+from repro.train.optimizer import OptimizerConfig
+from repro.train.trainer import Trainer, init_train_state, make_train_step
 
 KEY = jax.random.PRNGKey(11)
 TOL = 1e-4          # acceptance bound: max-abs error vs the dense reference
@@ -59,6 +62,10 @@ class TestKernelGrads:
         ("no_reset",    1,  64, 4, 2,  8, 16, 16, True,  True,  False),
         ("reset_only",  1,  64, 2, 2,  8, 16, 16, True,  False, True),
         ("odd_window",  1,  96, 2, 2,  8, 24, 32, True,  True,  True),
+        # band over the whole causal triangle, window not block-aligned:
+        # n_kv == n_q == 3, dead steps at the start of the fwd and dq walks
+        # and at the end of the dk/dv walk (the benchmark cell's schedule)
+        ("cell_band",   1,  96, 2, 2,  8, 61, 32, True,  True,  True),
     ])
     def test_dqkv_match_dense(self, name, B, S, H, Hk, D, W, blk,
                               sum_iso, nope, res):
@@ -202,6 +209,26 @@ class TestEndToEndGrads:
             assert np.isfinite(float(loss))
         err = _tree_max_err(grads["dense"], grads["pallas"])
         assert err <= TOL, f"param-grad mismatch {err}"
+
+
+@pytest.mark.parametrize("impl", ["pallas", "dense"])
+def test_trainer_band_gauges(impl):
+    """A Pallas LM's train step sets the trainer's winattn.* gauges to
+    ``band_steps`` at the batch's row length; a dense one sets none."""
+    cfg = _gqa_cfg(impl)
+    ocfg = OptimizerConfig(lr=1e-3)
+    step = make_train_step(make_lm_loss_fn(cfg, cfg.window), ocfg)
+    trainer = Trainer(step, init_train_state(
+        init_params(jax.random.PRNGKey(0), cfg), ocfg), log_every=100)
+    trainer.run(iter([_batch()]), n_steps=1)
+    snap = trainer.metrics.snapshot("winattn.")
+    if impl == "dense":
+        assert snap == {}
+        return
+    n_q = MAX_LEN // cfg.attn_block_size
+    assert (snap["winattn.grid_steps"]["value"],
+            snap["winattn.live_steps"]["value"]) == band_steps(
+                cfg.window, cfg.attn_block_size, n_q) == (8, 7)
 
 
 # ---------------------------------------------------------------------------

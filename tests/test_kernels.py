@@ -8,6 +8,9 @@ from repro.kernels.embedding_bag.ops import embedding_bag
 from repro.kernels.embedding_bag.ref import reference_embedding_bag
 from repro.kernels.windowed_attn.ops import windowed_attention
 from repro.kernels.windowed_attn.ref import reference_attention
+from repro.kernels.windowed_attn.windowed_attn import (band_steps,
+                                                       choose_block,
+                                                       n_kv_blocks)
 from repro.core.windowed import ResetConfig
 from repro.models.layers import alibi_slopes
 
@@ -21,6 +24,7 @@ class TestWindowedAttnKernel:
         (2, 256, 4, 4, 32, 128, 64),
         (1, 512, 8, 2, 64, 128, 128),
         (3, 192, 6, 3, 16, 64, 64),     # non-pow2 batch/heads
+        (1, 96, 2, 2, 8, 61, 32),       # n_kv == n_q == 3, dead steps
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_sweep(self, B, S, H, Hk, D, W, blk, dtype):
@@ -43,6 +47,23 @@ class TestWindowedAttnKernel:
                                   block_size=blk).astype(jnp.float32)
         tol = 2e-5 if dtype == jnp.float32 else 3e-2
         np.testing.assert_allclose(o_ref, o_pl, atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("S,blk,W,counts", [
+        (1536, 512, 977, (9, 6)),       # the benchmark cell's geometry
+        (96, 32, 61, (9, 6)),
+        (256, 32, 64, (24, 21)),        # block-aligned window
+        (2048, 256, 977, (40, 30)),
+        (64, 64, 16, (1, 1)),           # one block: no dead step
+    ])
+    def test_band_steps_counts_live_steps(self, S, blk, W, counts):
+        blk, s_pad = choose_block(S, blk)
+        n_q = s_pad // blk
+        n_kv = n_kv_blocks(W, blk, n_q)
+        fwd = sum(iq - (n_kv - 1) + ikv >= 0
+                  for iq in range(n_q) for ikv in range(n_kv))
+        dkv = sum(j + ib <= n_q - 1 for j in range(n_q) for ib in range(n_kv))
+        assert band_steps(W, blk, n_q) == (n_q * n_kv, fwd) == counts
+        assert dkv == fwd
 
     def test_jit_and_grad_through_kernel(self):
         B, S, H, D, W = 1, 128, 2, 16, 32
