@@ -30,31 +30,31 @@ from repro.kernels.windowed_attn.windowed_attn_bwd import (
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _attn(st: AttnStatics, q, k, v, qn, kn, v0, alibi,
-          pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k):
+          pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k, gate):
     out, _ = windowed_attention_fwd_bhsd(
         st, q, k, v, qn, kn, v0, alibi, pos_q, pos_k, sum_q, sum_k,
-        valid_k, seg_q, seg_k)
+        valid_k, seg_q, seg_k, gate)
     return out
 
 
 def _attn_fwd(st: AttnStatics, q, k, v, qn, kn, v0, alibi,
-              pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k):
+              pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k, gate):
     out, lse = windowed_attention_fwd_bhsd(
         st, q, k, v, qn, kn, v0, alibi, pos_q, pos_k, sum_q, sum_k,
-        valid_k, seg_q, seg_k)
+        valid_k, seg_q, seg_k, gate)
     return out, (q, k, v, qn, kn, v0, alibi, pos_q, pos_k, sum_q, sum_k,
-                 valid_k, seg_q, seg_k, out, lse)
+                 valid_k, seg_q, seg_k, gate, out, lse)
 
 
 def _attn_bwd(st: AttnStatics, res, do):
     (q, k, v, qn, kn, v0, alibi, pos_q, pos_k, sum_q, sum_k, valid_k,
-     seg_q, seg_k, out, lse) = res
+     seg_q, seg_k, gate, out, lse) = res
     dq, dk, dv, dqn, dkn, dv0 = windowed_attention_bwd_bhsd(
         st, q, k, v, qn, kn, v0, alibi, pos_q, pos_k, sum_q, sum_k,
-        valid_k, seg_q, seg_k, out, lse, do)
+        valid_k, seg_q, seg_k, gate, out, lse, do)
     # ALiBi slopes are head constants (alibi_slopes(n_heads)), not params
     return (dq, dk, dv, dqn, dkn, dv0, jnp.zeros_like(alibi),
-            None, None, None, None, None, None, None)
+            None, None, None, None, None, None, None, None)
 
 
 _attn.defvjp(_attn_fwd, _attn_bwd)

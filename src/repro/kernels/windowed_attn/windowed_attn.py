@@ -17,6 +17,12 @@ same pass as a second value stream: acc_r += w * a(d) * (v0 - v), folded
 into the final normalisation — zero extra HBM traffic for the reset beyond
 reading v0.
 
+The SUM-row work (the NoPE+ALiBi score and the reset stream) runs only on
+the 128-row query sub-tiles that hold a SUM query: a per-(row, q block)
+word of gate bits (``gate_bits``, in SMEM) says which. A q block that
+holds none runs whole; one that does runs sub-tile by sub-tile
+(``run_gated``; docs/kernels.md, the SUM gate).
+
 All mask/positional inputs are int32 (pos) / int32 (flags) so the kernel
 has no sub-byte loads. GQA is handled by index-mapping query head h onto
 kv head h // n_rep — K/V are never repeated in memory.
@@ -33,6 +39,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -79,16 +86,103 @@ def band_steps(window: int, blk: int, n_q: int) -> Tuple[int, int]:
     return n_q * n_kv, sum(min(i + 1, n_kv) for i in range(n_q))
 
 
+#: query rows of a SUM gate sub-tile: one MXU pass of rows
+SUM_SUB_TILE = 128
+
+
+class Band(NamedTuple):
+    """The windowed kernels' schedule at one row length, as the trainer's
+    ``winattn.*`` metrics count it."""
+    window: int
+    block: int
+    n_q: int
+    sub: int            # rows of a SUM gate sub-tile (``sub_tile``)
+    sum_stream: bool    # the calls run the NoPE or reset stream
+
+    def steps(self) -> Tuple[int, int]:
+        """-> (grid steps, live steps) per (row, head) and call."""
+        return band_steps(self.window, self.block, self.n_q)
+
+    def tile_steps(self, is_sum: np.ndarray) -> Tuple[int, int]:
+        """-> (live (sub-tile, kv-step) pairs that run the SUM stream, all
+        live pairs) of a (B, S) batch, per head and call. Q block ``i``
+        walks ``min(i + 1, n_kv)`` live kv steps."""
+        b, s = is_sum.shape
+        n_kv = n_kv_blocks(self.window, self.block, self.n_q)
+        live = np.minimum(np.arange(self.n_q) + 1, n_kv)
+        los = range(0, self.block, self.sub)
+        total = b * len(los) * int(live.sum())
+        if not self.sum_stream:
+            return 0, total
+        flags = np.zeros((b, self.n_q * self.block), bool)
+        flags[:, :s] = is_sum != 0
+        starts = [i * self.block + lo for i in range(self.n_q) for lo in los]
+        held = np.logical_or.reduceat(flags, starts, axis=1)
+        n_sum = (held.reshape(b, self.n_q, len(los)) * live[:, None]).sum()
+        return int(n_sum), total
+
+
+def sub_tile(blk: int) -> int:
+    """Rows of a block's SUM gate sub-tiles: ``min(128, blk)``."""
+    return min(SUM_SUB_TILE, blk)
+
+
+def sub_tiles(blk: int) -> Tuple[Tuple[int, int], ...]:
+    """Row spans ``(lo, hi)`` of a block's SUM gate sub-tiles (the last one
+    shorter when the sub-tile does not divide blk)."""
+    sub = sub_tile(blk)
+    return tuple((lo, min(lo + sub, blk)) for lo in range(0, blk, sub))
+
+
+def gate_bits(sum_q: jax.Array, blk: int) -> jax.Array:
+    """(B, 1, S_pad) SUM flags -> (B * n_q,) int32 gate words: bit ``t`` of
+    word ``b * n_q + i`` is set when sub-tile ``t`` of q block ``i`` of row
+    ``b`` holds a SUM query."""
+    b, _, s_pad = sum_q.shape
+    flags = (sum_q[:, 0] != 0).reshape(b, s_pad // blk, blk)
+    word = jnp.zeros((b, s_pad // blk), jnp.int32)
+    for t, (lo, hi) in enumerate(sub_tiles(blk)):
+        word |= flags[..., lo:hi].any(axis=-1).astype(jnp.int32) << t
+    return word.reshape(-1)
+
+
+def run_gated(bits, blk: int, rows, whole=None, then=None) -> None:
+    """Run a live step's query rows through ``rows(lo, hi, with_sum)``.
+
+    Without gate words (``bits`` None: the call runs no SUM stream), and
+    on a block that holds no SUM query (``bits`` 0), the block runs whole,
+    in the plain variant: ``whole()``, by default ``rows(0, blk, False)``.
+    Otherwise each sub-tile runs alone, in the SUM variant (``with_sum``
+    True) where its gate bit is set and in the plain one elsewhere, and
+    ``then()`` follows. ``rows`` acts through refs alone."""
+    whole = whole or functools.partial(rows, 0, blk, False)
+    if bits is None:
+        whole()
+        return
+    pl.when(bits == 0)(whole)
+
+    @pl.when(bits != 0)
+    def _split():
+        for t, (lo, hi) in enumerate(sub_tiles(blk)):
+            bit = (bits >> t) & 1
+            pl.when(bit != 0)(functools.partial(rows, lo, hi, True))
+            pl.when(bit == 0)(functools.partial(rows, lo, hi, False))
+        if then is not None:
+            then()
+
+
 def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
             seg_q_ref, seg_k_ref,
             alibi_ref,
-            q_ref, k_ref, v_ref, qn_ref, kn_ref, v0_ref,
-            o_ref, lse_ref,
-            m_ref, l_ref, acc_ref,
-            *, blk: int, n_kv: int, window: int, scale: float,
+            q_ref, k_ref, v_ref, qn_ref, kn_ref, v0_ref, *refs,
+            blk: int, n_q: int, n_kv: int, window: int, scale: float,
             sum_isolated: bool, use_seg: bool, use_nope: bool,
             use_reset: bool, y_min: float, y_max: float, midpoint: float):
-    ih, iq, ikv = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    gated = use_nope or use_reset
+    gate_ref, (o_ref, lse_ref, m_ref, l_ref, acc_ref) = (
+        (refs[0], refs[1:]) if gated else (None, refs))
+    ib, ih = pl.program_id(0), pl.program_id(1)
+    iq, ikv = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ikv == 0)
     def _init():
@@ -99,55 +193,65 @@ def _kernel(pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
     # a kv block before the sequence start adds nothing: skip the body
     @pl.when(iq - (n_kv - 1) + ikv >= 0)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)               # (blk, D)
-        k = k_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
-        # per-row operands arrive as (1, blk) rows; query-side ones are
-        # transposed to (blk, 1) columns so every mask term broadcasts 2-D
-        pos_q = pos_q_ref[0].T                            # (blk, 1) int32
-        pos_k = pos_k_ref[0]                              # (1, blk)
-        d = pos_q - pos_k                                 # (blk, blk)
-        sum_q = sum_q_ref[0].T != 0                       # (blk, 1)
-
-        if use_nope:
-            qn = qn_ref[0, 0].astype(jnp.float32)
-            kn = kn_ref[0, 0].astype(jnp.float32)
-            sn = jax.lax.dot_general(
-                qn, kn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            sn = sn - alibi_ref[ih] * d.astype(jnp.float32)
-            s = jnp.where(sum_q, sn, s)
-
-        # mask: causal + window + key-padding (+ SUM isolation) (+ segment)
-        mask = (d >= 0) & (d <= window) & (valid_k_ref[0] != 0)
-        if sum_isolated:
-            mask &= (sum_k_ref[0] == 0) | (d == 0)
-        if use_seg:
-            mask &= seg_q_ref[0].T == seg_k_ref[0]
-        s = jnp.where(mask, s, NEG_INF)
-
-        # online softmax
-        m_prev = m_ref[...]                               # (blk, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        w = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-
+        k = k_ref[0, 0].astype(jnp.float32)               # (blk, D)
         v = v_ref[0, 0].astype(jnp.float32)
-        acc = acc_ref[...] * alpha
-        acc += jax.lax.dot_general(w, v, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        if use_reset:
-            a = y_min + (y_max - y_min) * jax.nn.sigmoid(
-                d.astype(jnp.float32) - midpoint)
-            wr = w * a * sum_q.astype(jnp.float32)
-            dv = v0_ref[0, 0].astype(jnp.float32) - v
-            acc += jax.lax.dot_general(wr, dv, (((1,), (0,)), ((), ())),
+        pos_k = pos_k_ref[0]                              # (1, blk)
+
+        def rows(lo, hi, with_sum):
+            # query rows lo:hi; the SUM variant adds the NoPE+ALiBi score
+            # and the reset stream on the SUM rows among them
+            q = q_ref[0, 0, lo:hi, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+
+            # per-row operands arrive as (1, blk) rows; query-side ones are
+            # transposed to columns so every mask term broadcasts 2-D
+            pos_q = pos_q_ref[0, :, lo:hi].T              # (rows, 1) int32
+            d = pos_q - pos_k                             # (rows, blk)
+            sum_q = sum_q_ref[0, :, lo:hi].T != 0         # (rows, 1)
+
+            if with_sum and use_nope:
+                qn = qn_ref[0, 0, lo:hi, :].astype(jnp.float32)
+                kn = kn_ref[0, 0].astype(jnp.float32)
+                sn = jax.lax.dot_general(
+                    qn, kn, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                sn = sn - alibi_ref[ih] * d.astype(jnp.float32)
+                s = jnp.where(sum_q, sn, s)
+
+            # mask: causal + window + key-padding (+ SUM isolation)
+            # (+ segment)
+            mask = (d >= 0) & (d <= window) & (valid_k_ref[0] != 0)
+            if sum_isolated:
+                mask &= (sum_k_ref[0] == 0) | (d == 0)
+            if use_seg:
+                mask &= seg_q_ref[0, :, lo:hi].T == seg_k_ref[0]
+            s = jnp.where(mask, s, NEG_INF)
+
+            # online softmax
+            m_prev = m_ref[lo:hi, :]                      # (rows, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            w = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            l_ref[lo:hi, :] = (l_ref[lo:hi, :] * alpha
+                               + jnp.sum(w, axis=-1, keepdims=True))
+            m_ref[lo:hi, :] = m_new
+
+            acc = acc_ref[lo:hi, :] * alpha
+            acc += jax.lax.dot_general(w, v, (((1,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
-        acc_ref[...] = acc
+            if with_sum and use_reset:
+                a = y_min + (y_max - y_min) * jax.nn.sigmoid(
+                    d.astype(jnp.float32) - midpoint)
+                wr = w * a * sum_q.astype(jnp.float32)
+                dv = v0_ref[0, 0].astype(jnp.float32) - v
+                acc += jax.lax.dot_general(
+                    wr, dv, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            acc_ref[lo:hi, :] = acc
+
+        run_gated(gate_ref[ib * n_q + iq] if gated else None, blk, rows)
 
     @pl.when(ikv == n_kv - 1)
     def _finish():
@@ -187,10 +291,12 @@ def prepare_inputs(
 
     The array tuple is exactly the differentiable-argument order of the
     custom_vjp in ops.py: (q, k, v, qn, kn, v0, alibi, pos_q, pos_k,
-    sum_q, sum_k, valid_k, seg_q, seg_k). The sequence axis is padded to
-    a block multiple (padded keys carry ``valid_k = 0``; the caller drops
+    sum_q, sum_k, valid_k, seg_q, seg_k, gate). The sequence axis is padded
+    to a block multiple (padded keys carry ``valid_k = 0``; the caller drops
     the padded query rows), and every per-row int operand is laid out as
     ``(B, 1, S_pad)`` so its kernel block ``(1, blk)`` is lane-major.
+    ``gate`` holds the SUM gate words (``gate_bits``) when the NoPE or
+    reset stream is live, and is None otherwise.
     """
     b, h, s, d = q.shape
     blk, s_pad = choose_block(s, block_size)
@@ -225,19 +331,20 @@ def prepare_inputs(
                      use_nope=use_nope, use_reset=use_reset,
                      y_min=float(y_min), y_max=float(y_max),
                      midpoint=float(midpoint), interpret=bool(interpret))
+    sum_q_row = row(sum_q if sum_q is not None else zeros)
+    gate = gate_bits(sum_q_row, blk) if use_nope or use_reset else None
     arrays = (seq(q), seq(k), seq(v), qn, kn, v0_, alibi_f,
-              row(pos_q), row(pos_k),
-              row(sum_q if sum_q is not None else zeros),
+              row(pos_q), row(pos_k), sum_q_row,
               row(sum_k if sum_k is not None else zeros),
               row(valid_k if valid_k is not None else zeros + 1),
               row(seg_q if use_seg else zeros),
-              row(seg_k if use_seg else zeros))
+              row(seg_k if use_seg else zeros), gate)
     return st, arrays
 
 
 def windowed_attention_fwd_bhsd(
         st: AttnStatics, q, k, v, qn, kn, v0, alibi,
-        pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
+        pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k, gate,
 ) -> Tuple[jax.Array, jax.Array]:
     """Normalised forward: returns (o (B,H,S,Dv), lse (B,H,1,S) fp32)."""
     b, h, s, d = q.shape
@@ -270,10 +377,14 @@ def windowed_attention_fwd_bhsd(
     qn_map = q_idx if st.use_nope else kvh_idx
     v0_map = kv_idx if st.use_reset else kvh_idx
 
+    # the SUM gate words ride after the tiles, in SMEM like alibi
+    gated = gate is not None
+    gate_spec = [pl.BlockSpec(memory_space=pltpu.SMEM)] if gated else []
     grid = (b, h, n_q, n_kv)
     out, lse = pl.pallas_call(
         functools.partial(
-            _kernel, blk=blk, n_kv=n_kv, window=st.window, scale=st.scale,
+            _kernel, blk=blk, n_q=n_q, n_kv=n_kv, window=st.window,
+            scale=st.scale,
             sum_isolated=st.sum_isolated, use_seg=st.use_seg,
             use_nope=st.use_nope, use_reset=st.use_reset, y_min=st.y_min,
             y_max=st.y_max, midpoint=st.midpoint),
@@ -293,7 +404,7 @@ def windowed_attention_fwd_bhsd(
             pl.BlockSpec((1, 1, blk, d), qn_map),               # qn
             pl.BlockSpec((1, 1, blk, d), kn_map),               # kn
             pl.BlockSpec((1, 1, blk, dv), v0_map),              # v0
-        ],
+        ] + gate_spec,
         out_specs=[
             pl.BlockSpec((1, 1, blk, dv), q_idx),
             pl.BlockSpec((1, 1, 1, blk),
@@ -313,9 +424,11 @@ def windowed_attention_fwd_bhsd(
                                  "arbitrary")),
         interpret=st.interpret, name="winattn_fwd",
     )(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k, alibi, q, k, v,
-      qn, kn, v0)
+      qn, kn, v0, *([gate] if gated else []))
     return out, lse
 
 
-__all__ = ["AttnStatics", "band_steps", "choose_block", "n_kv_blocks",
-           "prepare_inputs", "windowed_attention_fwd_bhsd"]
+__all__ = ["AttnStatics", "Band", "SUM_SUB_TILE", "band_steps",
+           "choose_block", "gate_bits", "n_kv_blocks", "prepare_inputs",
+           "run_gated", "sub_tile", "sub_tiles",
+           "windowed_attention_fwd_bhsd"]

@@ -15,6 +15,10 @@ Two passes over the same window-banded block schedule as the forward
   *query* head, and the wrapper reduces query-head groups onto kv heads
   (GQA) outside — K/V are never repeated in memory, matching the forward.
 
+Both passes gate the SUM rows' work by the forward's sub-tile gate words
+(``run_gated``): a q block with no SUM query runs whole and plain, one
+with SUM queries runs sub-tile by sub-tile.
+
 DTI semantics and where their gradients flow:
 
 * mask terms (causal window, ``valid_k``, SUM isolation, packed segments)
@@ -39,7 +43,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.windowed_attn.windowed_attn import (AttnStatics,
-                                                       n_kv_blocks)
+                                                       n_kv_blocks,
+                                                       run_gated)
 
 _f32 = jnp.float32
 
@@ -49,23 +54,27 @@ def _dot(a, b, dims):
                                preferred_element_type=_f32)
 
 
-def _recompute_tile(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
-                    alibi, q, k, qn, kn, v, v0, do, lse, delta,
-                    *, window, scale, sum_isolated, use_seg, use_nope,
-                    use_reset, y_min, y_max, midpoint):
-    """Shared (q-block, kv-block) tile math for both backward passes.
+def _recompute_rows(pos_q, sum_q, seg_q, q, do, lse, delta,
+                    pos_k, sum_k, valid_k, seg_k, alibi, k, v,
+                    qn, kn, v0, *, with_sum, window, scale, sum_isolated,
+                    use_seg, use_nope, use_reset, y_min, y_max, midpoint):
+    """Shared tile math of both backward passes, for some query rows.
 
-    Query-side rows (pos_q, sum_q, seg_q, lse, delta) arrive as (blk, 1)
-    columns and key-side ones as (1, blk) rows. Returns (p, ds_rope,
-    ds_nope, asig): probabilities, the score gradient split by stream (RoPE
-    rows vs SUM NoPE rows), and the reset weight a(d)*sigma (None unless
-    the reset stream is live). All fp32.
+    Query-side operands (pos_q, sum_q, seg_q, lse, delta) arrive as
+    (rows, 1) columns with q and do as (rows, D) tiles, key-side ones as
+    (1, blk) rows with k and v as (blk, D) tiles. ``qn``, ``kn`` and ``v0``
+    are read only in the SUM variant (``with_sum``), which takes the SUM
+    rows' score from the NoPE stream and adds the reset term to their
+    ``dp``. Returns (pv, ds_rope, ds_nope, pa): the main value stream's
+    weight (``p * (1 - a*sigma)``, or the probabilities p), the score
+    gradient split by stream (RoPE rows vs SUM NoPE rows) and the reset
+    weight ``p * a*sigma`` (None where a stream does not run). All fp32.
     """
-    s = _dot(q, k, ((1,), (1,))) * scale                  # (blk_q, blk_k)
+    s = _dot(q, k, ((1,), (1,))) * scale                  # (rows, blk)
     d = pos_q - pos_k
     sum_row = sum_q != 0
-    if use_nope:
-        sn = _dot(qn, kn, ((1,), (1,))) * scale
+    if with_sum and use_nope:
+        sn = _dot(qn(), kn(), ((1,), (1,))) * scale
         sn = sn - alibi * d.astype(_f32)
         s = jnp.where(sum_row, sn, s)
 
@@ -80,43 +89,50 @@ def _recompute_tile(pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
     p = jnp.where(mask, jnp.exp(s - lse), 0.0)
 
     dp = _dot(do, v, ((1,), (1,)))                        # do . v_j
-    asig = None
-    if use_reset:
+    pv = pa = ds_nope = None
+    if with_sum and use_reset:
         a = y_min + (y_max - y_min) * jax.nn.sigmoid(
             d.astype(_f32) - midpoint)
         asig = a * sum_row.astype(_f32)
-        dp = dp + asig * _dot(do, v0 - v, ((1,), (1,)))
+        dp = dp + asig * _dot(do, v0() - v, ((1,), (1,)))
+        pv, pa = p * (1.0 - asig), p * asig
     ds = p * (dp - delta)
-    if use_nope:
+    if with_sum and use_nope:
         ds_nope = ds * sum_row.astype(_f32)
-        ds_rope = ds - ds_nope
-    else:
-        ds_rope, ds_nope = ds, None
-    return p, ds_rope, ds_nope, asig
+        ds = ds - ds_nope
+    return (p if pv is None else pv), ds, ds_nope, pa
 
 
-def _load_tile(head, pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref, valid_k_ref,
-               seg_q_ref, seg_k_ref, alibi_ref, q_ref, k_ref, v_ref,
-               qn_ref, kn_ref, v0_ref, do_ref, lse_ref, delta_ref):
-    # query-side rows transpose to (blk, 1) columns, key-side stay (1, blk)
+def _tile_operands(head, lo, hi, pos_q_ref, pos_k_ref, sum_q_ref, sum_k_ref,
+                   valid_k_ref, seg_q_ref, seg_k_ref, alibi_ref, q_ref, k_ref,
+                   v_ref, qn_ref, kn_ref, v0_ref, do_ref, lse_ref, delta_ref):
+    """``_recompute_rows``' operands for query rows lo:hi: query-side rows
+    transpose to (rows, 1) columns, key-side ones stay (1, blk) rows, and
+    the SUM streams' tiles are read on call, in the SUM variant only."""
     return dict(
-        pos_q=pos_q_ref[0].T, pos_k=pos_k_ref[0], sum_q=sum_q_ref[0].T,
-        sum_k=sum_k_ref[0], valid_k=valid_k_ref[0], seg_q=seg_q_ref[0].T,
+        pos_q=pos_q_ref[0, :, lo:hi].T, sum_q=sum_q_ref[0, :, lo:hi].T,
+        seg_q=seg_q_ref[0, :, lo:hi].T,
+        q=q_ref[0, 0, lo:hi, :].astype(_f32),
+        do=do_ref[0, 0, lo:hi, :].astype(_f32),
+        lse=lse_ref[0, 0, :, lo:hi].T, delta=delta_ref[0, 0, :, lo:hi].T,
+        pos_k=pos_k_ref[0], sum_k=sum_k_ref[0], valid_k=valid_k_ref[0],
         seg_k=seg_k_ref[0], alibi=alibi_ref[head],
-        q=q_ref[0, 0].astype(_f32), k=k_ref[0, 0].astype(_f32),
-        qn=qn_ref[0, 0].astype(_f32), kn=kn_ref[0, 0].astype(_f32),
-        v=v_ref[0, 0].astype(_f32), v0=v0_ref[0, 0].astype(_f32),
-        do=do_ref[0, 0].astype(_f32), lse=lse_ref[0, 0].T,
-        delta=delta_ref[0, 0].T)
+        k=k_ref[0, 0].astype(_f32), v=v_ref[0, 0].astype(_f32),
+        qn=lambda: qn_ref[0, 0, lo:hi, :].astype(_f32),
+        kn=lambda: kn_ref[0, 0].astype(_f32),
+        v0=lambda: v0_ref[0, 0].astype(_f32))
 
 
-def _dq_kernel(*refs, n_kv: int, use_nope: bool, scale: float, math_kw):
+def _dq_kernel(*refs, blk: int, n_q: int, n_kv: int, use_nope: bool,
+               gated: bool, scale: float, math_kw):
     ins, refs = refs[:17], refs[17:]
+    gate_ref, refs = (refs[0], refs[1:]) if gated else (None, refs)
     if use_nope:
         dq_ref, dqn_ref, dq_acc, dqn_acc = refs
     else:
         (dq_ref, dq_acc), dqn_ref, dqn_acc = refs, None, None
-    ih, iq, ikv = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ib, ih = pl.program_id(0), pl.program_id(1)
+    iq, ikv = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ikv == 0)
     def _init():
@@ -126,11 +142,16 @@ def _dq_kernel(*refs, n_kv: int, use_nope: bool, scale: float, math_kw):
 
     @pl.when(iq - (n_kv - 1) + ikv >= 0)      # kv block inside the band
     def _step():
-        t = _load_tile(ih, *ins)
-        _, ds_rope, ds_nope, _ = _recompute_tile(**t, **math_kw)
-        dq_acc[...] += scale * _dot(ds_rope, t["k"], ((1,), (0,)))
-        if use_nope:
-            dqn_acc[...] += scale * _dot(ds_nope, t["kn"], ((1,), (0,)))
+        def rows(lo, hi, with_sum):
+            t = _tile_operands(ih, lo, hi, *ins)
+            _, ds_rope, ds_nope, _ = _recompute_rows(
+                **t, with_sum=with_sum, **math_kw)
+            dq_acc[lo:hi, :] += scale * _dot(ds_rope, t["k"], ((1,), (0,)))
+            if with_sum and use_nope:
+                dqn_acc[lo:hi, :] += scale * _dot(ds_nope, t["kn"](),
+                                                  ((1,), (0,)))
+
+        run_gated(gate_ref[ib * n_q + iq] if gated else None, blk, rows)
 
     @pl.when(ikv == n_kv - 1)
     def _finish():
@@ -139,18 +160,20 @@ def _dq_kernel(*refs, n_kv: int, use_nope: bool, scale: float, math_kw):
             dqn_ref[0, 0, ...] = dqn_acc[...].astype(dqn_ref.dtype)
 
 
-def _dkv_kernel(*refs, n_kv: int, n_q: int, use_nope: bool,
+def _dkv_kernel(*refs, blk: int, n_kv: int, n_q: int, use_nope: bool,
                 use_reset: bool, scale: float, math_kw):
     ins, refs = refs[:17], refs[17:]
+    gated = use_nope or use_reset
+    gate_ref, refs = (refs[0], refs[1:]) if gated else (None, refs)
     n_out = 2 + int(use_nope) + int(use_reset)
-    outs, accs = refs[:n_out], refs[n_out:]
-    dk_ref, dv_ref = outs[0], outs[1]
+    outs, accs = refs[:n_out], refs[n_out:2 * n_out]
+    # gated calls stage pv and ds_rope per sub-tile for the full-tile dots
+    pv_ref, ds_ref = refs[2 * n_out:] if gated else (None, None)
     dk_acc, dv_acc = accs[0], accs[1]
-    dkn_ref = outs[2] if use_nope else None
     dkn_acc = accs[2] if use_nope else None
-    dv0_ref = outs[2 + int(use_nope)] if use_reset else None
     dv0_acc = accs[2 + int(use_nope)] if use_reset else None
-    ih, j, ib = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ibt, ih = pl.program_id(0), pl.program_id(1)
+    j, ib = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ib == 0)
     def _init():
@@ -159,15 +182,39 @@ def _dkv_kernel(*refs, n_kv: int, n_q: int, use_nope: bool,
 
     @pl.when(j + ib <= n_q - 1)               # q block inside the sequence
     def _step():
-        t = _load_tile(ih, *ins)
-        p, ds_rope, ds_nope, asig = _recompute_tile(**t, **math_kw)
-        pv = p if not use_reset else p * (1.0 - asig)
-        dv_acc[...] += _dot(pv, t["do"], ((0,), (0,)))
-        if use_reset:
-            dv0_acc[...] += _dot(p * asig, t["do"], ((0,), (0,)))
-        dk_acc[...] += scale * _dot(ds_rope, t["q"], ((0,), (0,)))
-        if use_nope:
-            dkn_acc[...] += scale * _dot(ds_nope, t["qn"], ((0,), (0,)))
+        def dkv(pv, ds_rope, do, q):
+            dv_acc[...] += _dot(pv, do, ((0,), (0,)))
+            dk_acc[...] += scale * _dot(ds_rope, q, ((0,), (0,)))
+
+        def whole():
+            t = _tile_operands(ih, 0, blk, *ins)
+            pv, ds_rope, _, _ = _recompute_rows(
+                **t, with_sum=False, **math_kw)
+            dkv(pv, ds_rope, t["do"], t["q"])
+
+        def rows(lo, hi, with_sum):
+            # a sub-tile of a block that holds a SUM: stage its pv and
+            # ds_rope rows for the block's dk/dv products in ``then``
+            t = _tile_operands(ih, lo, hi, *ins)
+            pv, ds_rope, ds_nope, pa = _recompute_rows(
+                **t, with_sum=with_sum, **math_kw)
+            pv_ref[lo:hi, :] = pv
+            ds_ref[lo:hi, :] = ds_rope
+            if with_sum and use_nope:
+                dkn_acc[...] += scale * _dot(ds_nope, t["qn"](),
+                                             ((0,), (0,)))
+            if with_sum and use_reset:
+                dv0_acc[...] += _dot(pa, t["do"], ((0,), (0,)))
+
+        def then():
+            q_ref, do_ref = ins[8], ins[14]       # in_specs order
+            dkv(pv_ref[...], ds_ref[...], do_ref[0, 0].astype(_f32),
+                q_ref[0, 0].astype(_f32))
+
+        # the walked q block, through the clamp of the index maps
+        bits = (gate_ref[ibt * n_q + jnp.minimum(j + ib, n_q - 1)]
+                if gated else None)
+        run_gated(bits, blk, rows, whole=whole, then=then)
 
     @pl.when(ib == n_kv - 1)
     def _finish():
@@ -187,7 +234,7 @@ def _head_sum(x: jax.Array, n_out: int) -> jax.Array:
 
 def windowed_attention_bwd_bhsd(
         st: AttnStatics, q, k, v, qn, kn, v0, alibi,
-        pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k,
+        pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k, gate,
         o, lse, do) -> Tuple[jax.Array, ...]:
     """Backward over normalised operands. Returns (dq, dk, dv, dqn, dkn,
     dv0); streams that are not live come back as zeros of the dummy
@@ -239,6 +286,10 @@ def windowed_attention_bwd_bhsd(
     qn_map = q_idx if st.use_nope else kvh_idx
     v0_map = kv_idx if st.use_reset else kvh_idx
 
+    # the SUM gate words ride after the tiles, in SMEM like alibi
+    gated = gate is not None
+    gate_spec = [pl.BlockSpec(memory_space=pltpu.SMEM)] if gated else []
+
     def in_specs(sq, sk, qm, km, vm, qnm, knm, v0m, rowm):
         return [
             pl.BlockSpec((1, 1, blk), sq),                  # pos_q
@@ -258,10 +309,11 @@ def windowed_attention_bwd_bhsd(
             pl.BlockSpec((1, 1, blk, dv_d), qm),            # do
             pl.BlockSpec((1, 1, 1, blk), rowm),             # lse
             pl.BlockSpec((1, 1, 1, blk), rowm),             # delta
-        ]
+        ] + gate_spec
 
     operands = (pos_q, pos_k, sum_q, sum_k, valid_k, seg_q, seg_k, alibi,
-                q, k, v, qn, kn, v0, do, lse, delta)
+                q, k, v, qn, kn, v0, do, lse, delta) + (
+                    (gate,) if gated else ())
 
     dq_outs = [jax.ShapeDtypeStruct((b, h, s, d), q.dtype)]
     dq_specs = [pl.BlockSpec((1, 1, blk, d), q_idx)]
@@ -271,7 +323,8 @@ def windowed_attention_bwd_bhsd(
         dq_specs.append(pl.BlockSpec((1, 1, blk, d), q_idx))
         dq_scratch.append(pltpu.VMEM((blk, d), _f32))
     res = pl.pallas_call(
-        functools.partial(_dq_kernel, n_kv=n_kv, use_nope=st.use_nope,
+        functools.partial(_dq_kernel, blk=blk, n_q=n_q, n_kv=n_kv,
+                          use_nope=st.use_nope, gated=gated,
                           scale=st.scale, math_kw=math_kw),
         grid=grid,
         in_specs=in_specs(seq_q_idx, seq_k_idx, q_idx, kv_idx, kv_idx,
@@ -319,9 +372,10 @@ def windowed_attention_bwd_bhsd(
                 for dd in out_dims]
     dkv_specs = [pl.BlockSpec((1, 1, blk, dd), b_out_idx)
                  for dd in out_dims]
-    dkv_scratch = [pltpu.VMEM((blk, dd), _f32) for dd in out_dims]
+    dkv_scratch = [pltpu.VMEM((blk, dd), _f32) for dd in out_dims] + (
+        [pltpu.VMEM((blk, blk), _f32)] * 2 if gated else [])
     res = pl.pallas_call(
-        functools.partial(_dkv_kernel, n_kv=n_kv, n_q=n_q,
+        functools.partial(_dkv_kernel, blk=blk, n_kv=n_kv, n_q=n_q,
                           use_nope=st.use_nope, use_reset=st.use_reset,
                           scale=st.scale, math_kw=math_kw),
         grid=grid,

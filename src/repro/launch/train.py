@@ -105,8 +105,10 @@ def make_lm_loss_fn(cfg: ModelConfig, window: int):
         return loss + out["aux_loss"], {}
     if cfg.attn_impl == "pallas":
         # the windowed kernels' band at a row length, for the trainer's
-        # winattn.* gauges (make_train_step carries it to the step)
-        loss_fn.attn_band = functools.partial(attn_band, cfg, window)
+        # winattn.* metrics (make_train_step carries it to the step); the
+        # trainer asks for it every batch, and rows keep a few lengths
+        loss_fn.attn_band = functools.lru_cache(maxsize=8)(
+            functools.partial(attn_band, cfg, window))
     return loss_fn
 
 

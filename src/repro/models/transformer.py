@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -165,13 +165,16 @@ def _attn_block(cfg: ModelConfig, seq: int) -> int:
     return train_block(seq, cfg.hd)
 
 
-def attn_band(cfg: ModelConfig, window: int, seq: int) -> Tuple[int, int]:
-    """-> (grid steps, live steps) of one (row, head) in each windowed-kernel
-    call at row length ``seq`` (``band_steps`` at the forward's block)."""
-    from repro.kernels.windowed_attn.windowed_attn import (band_steps,
-                                                           choose_block)
+def attn_band(cfg: ModelConfig, window: int, seq: int):
+    """The windowed kernels' ``Band`` at row length ``seq``: the forward's
+    block, its SUM gate sub-tile, and whether the DTI loss's calls run the
+    SUM stream (NoPE+ALiBi or the reset)."""
+    from repro.kernels.windowed_attn.windowed_attn import (Band,
+                                                           choose_block,
+                                                           sub_tile)
     blk, s_pad = choose_block(seq, _attn_block(cfg, seq))
-    return band_steps(window, blk, s_pad // blk)
+    return Band(window, blk, s_pad // blk, sub_tile(blk),
+                cfg.dti_sum_token and (cfg.dti_sum_alibi or cfg.dti_reset))
 
 
 def _layer_fwd(lp: Params, h: jax.Array, cfg: ModelConfig, kind: str, *,

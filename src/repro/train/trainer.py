@@ -56,7 +56,7 @@ def make_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
     With ``opt_cfg.trainable == "lora"`` the loss sees the base weights
     through ``freeze_non_lora``, so only the adapter factors get grads.
     The returned step carries ``loss_fn.attn_band`` (or None) as its own
-    ``attn_band``, which ``Trainer`` reads for its ``winattn.*`` gauges."""
+    ``attn_band``, which ``Trainer`` reads for its ``winattn.*`` metrics."""
     attn_band = getattr(loss_fn, "attn_band", None)
     if opt_cfg.trainable == "lora":
         base_loss = loss_fn
@@ -142,7 +142,10 @@ class Trainer:
     with an ``attn_band`` (a Pallas-attention LM's, ``make_train_step``)
     sets gauges ``winattn.grid_steps`` / ``winattn.live_steps`` once, for
     the first batch's row length: the windowed kernels' grid steps and
-    the steps that run their body, per (row, head) and call.
+    the steps that run their body, per (row, head) and call. From each
+    NumPy batch's ``is_sum`` it counts ``winattn.tile_steps``, the live
+    (query sub-tile, kv step) pairs of each call per head, and
+    ``winattn.sum_tile_steps``, those of them that run the SUM stream.
     """
     step_fn: Callable
     state: TrainState
@@ -175,11 +178,17 @@ class Trainer:
             m.counter("train.pad_tokens").inc(int(valid.size - valid.sum()))
         band = getattr(self.step_fn, "attn_band", None)
         tokens = batch.get("tokens") if isinstance(batch, dict) else None
-        if band is not None and tokens is not None \
-                and not m.names("winattn."):
-            grid, live = band(tokens.shape[1])
+        if band is None or tokens is None:
+            return
+        geo = band(tokens.shape[1])
+        if not m.names("winattn.grid_steps"):
+            grid, live = geo.steps()
             m.gauge("winattn.grid_steps").set(grid)
             m.gauge("winattn.live_steps").set(live)
+        if isinstance(is_sum, np.ndarray):
+            n_sum, n_all = geo.tile_steps(is_sum)
+            m.counter("winattn.sum_tile_steps").inc(n_sum)
+            m.counter("winattn.tile_steps").inc(n_all)
 
     def timing(self) -> Dict[str, float]:
         """Compile-vs-steady split of this trainer's executed steps:

@@ -27,7 +27,9 @@ from repro.core.dti import build_streaming_prompts, pack_prompts
 from repro.core.windowed import (ResetConfig, attention_blocked,
                                  attention_dense)
 from repro.kernels.windowed_attn.ops import windowed_attention
-from repro.kernels.windowed_attn.windowed_attn import band_steps
+from repro.kernels.windowed_attn.windowed_attn import (Band, band_steps,
+                                                       choose_block,
+                                                       n_kv_blocks)
 from repro.launch.train import make_lm_loss_fn
 from repro.models.layers import alibi_slopes
 from repro.models.transformer import ModelConfig, init_params
@@ -36,6 +38,7 @@ from repro.train.trainer import Trainer, init_train_state, make_train_step
 
 KEY = jax.random.PRNGKey(11)
 TOL = 1e-4          # acceptance bound: max-abs error vs the dense reference
+FWD_TOL = 2e-5      # the forward's bound (tests/test_kernels.py, fp32)
 
 
 def _rand(shape, i, dtype=jnp.float32):
@@ -53,6 +56,46 @@ def _tree_max_err(a, b):
 # kernel-level dq/dk/dv equivalence
 # ---------------------------------------------------------------------------
 
+# SUM-row layouts by case name (default: 15 % of rows at random). ``sums``
+# lists each batch row's SUM positions; ``packed`` gives segment lengths
+# (positions restart per segment, SUMs on each segment's tail); ``dv`` a
+# value dim other than D. Blocks of 256 rows hold two 128-row sub-tiles.
+_LAYOUTS = {
+    "sums_none": dict(sums=[[]]),
+    "sums_one_tile": dict(sums=[[261, 300], [10, 100]]),
+    "sums_every_tile": dict(sums=[[64, 200, 320, 450], [0, 255, 256, 511]]),
+    "sums_tile_edge": dict(sums=[[127, 128]]),
+    "sums_packed": dict(packed=(150, 200, 162)),
+    "nope_only_tiles": dict(sums=[[261, 300]]),
+    "reset_only_tiles": dict(sums=[[261, 300]]),
+    "mla_one_kn": dict(dv=8),
+    "ragged_sub_tile": dict(sums=[[140, 180]]),     # sub-tiles 128 + 64
+    "small_blk_sparse": dict(sums=[[70, 75]]),      # blk 32 < 128
+}
+
+
+def _sum_layout(name, r, B, S):
+    """-> (is_sum, pos, seg or None) of the case's SUM-row layout."""
+    lay = _LAYOUTS.get(name, {})
+    pos = np.broadcast_to(np.arange(S), (B, S))
+    seg = None
+    if "sums" in lay:
+        is_sum = np.zeros((B, S), bool)
+        for b, rows in enumerate(lay["sums"]):
+            is_sum[b, rows] = True
+    elif "packed" in lay:
+        lens = lay["packed"]
+        seg = np.repeat(np.arange(len(lens)), lens)[None].repeat(B, 0)
+        pos = np.concatenate([np.arange(n) for n in lens])[None].repeat(B, 0)
+        is_sum = np.zeros((B, S), bool)
+        for end in np.cumsum(lens):
+            is_sum[:, end - 1 - 3 * np.arange(6)] = True
+    else:
+        is_sum = r.random((B, S)) < 0.15
+    return (jnp.asarray(is_sum), jnp.asarray(pos, jnp.int32),
+            None if seg is None else jnp.asarray(seg, jnp.int32))
+
+
 class TestKernelGrads:
     @pytest.mark.parametrize("name,B,S,H,Hk,D,W,blk,sum_iso,nope,res", [
         ("gqa_full",    2, 128, 4, 2, 16, 32, 32, True,  True,  True),
@@ -66,23 +109,44 @@ class TestKernelGrads:
         # n_kv == n_q == 3, dead steps at the start of the fwd and dq walks
         # and at the end of the dk/dv walk (the benchmark cell's schedule)
         ("cell_band",   1,  96, 2, 2,  8, 61, 32, True,  True,  True),
+        # SUM gate sub-tiles (_LAYOUTS): which 128-row query sub-tiles of
+        # a block run the NoPE and reset streams
+        ("sums_none",        1, 512, 2, 1,  8, 200, 256, True, True,  True),
+        ("sums_one_tile",    2, 512, 2, 1,  8, 200, 256, True, True,  True),
+        ("sums_every_tile",  2, 512, 2, 1,  8, 200, 256, True, True,  True),
+        ("sums_tile_edge",   1, 512, 2, 1,  8, 200, 256, True, True,  True),
+        ("sums_packed",      1, 512, 2, 1,  8, 200, 256, True, True,  True),
+        ("nope_only_tiles",  1, 512, 2, 1,  8, 200, 256, True, True,  False),
+        ("reset_only_tiles", 1, 512, 2, 1,  8, 200, 256, True, False, True),
+        ("mla_one_kn",       1, 512, 2, 1, 16, 200, 256, True, True,  True),
+        ("ragged_sub_tile",  1, 384, 2, 1,  8, 200, 192, True, True,  True),
+        ("small_blk_sparse", 1, 128, 2, 1,  8,  32,  32, True, True,  True),
     ])
     def test_dqkv_match_dense(self, name, B, S, H, Hk, D, W, blk,
                               sum_iso, nope, res):
+        """Forward output and all six gradients against the dense path."""
         r = np.random.default_rng(len(name))
+        dv = _LAYOUTS.get(name, {}).get("dv", D)
         q, qn = _rand((B, S, H, D), 0), _rand((B, S, H, D), 3)
         k, kn = _rand((B, S, Hk, D), 1), _rand((B, S, Hk, D), 4)
-        v, v0 = _rand((B, S, Hk, D), 2), _rand((B, S, Hk, D), 5)
-        w = _rand((B, S, H, D), 9)
-        pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-        is_sum = jnp.asarray(r.random((B, S)) < 0.15)
+        v, v0 = _rand((B, S, Hk, dv), 2), _rand((B, S, Hk, dv), 5)
+        w = _rand((B, S, H, dv), 9)
+        is_sum, pos, seg = _sum_layout(name, r, B, S)
         valid = jnp.asarray(r.random((B, S)) < 0.9)
         kw = dict(pos_q=pos, pos_k=pos, window=W, is_sum_q=is_sum,
                   is_sum_k=is_sum, valid_k=valid, sum_isolated=sum_iso)
+        if seg is not None:
+            kw.update(seg_q=seg, seg_k=seg)
         if nope:
             kw.update(q_nope=qn, k_nope=kn, alibi=alibi_slopes(H))
         if res:
             kw.update(v0=v0, reset=ResetConfig(0.05, 0.3, W / 2))
+
+        pallas = lambda *a, **kk: windowed_attention(*a, **kk,
+                                                     block_size=blk)
+        np.testing.assert_allclose(
+            np.asarray(attention_dense(q, k, v, **kw)),
+            np.asarray(pallas(q, k, v, **kw)), atol=FWD_TOL, rtol=FWD_TOL)
 
         def loss(fn, extra=()):
             def f(q, k, v, *rest):
@@ -97,10 +161,7 @@ class TestKernelGrads:
         rest = tuple({"q_nope": qn, "k_nope": kn, "v0": v0}[e] for e in extra)
         argn = tuple(range(3 + len(rest)))
         g_ref = jax.grad(loss(attention_dense, extra), argn)(q, k, v, *rest)
-        g_pl = jax.grad(
-            loss(lambda *a, **kk: windowed_attention(*a, **kk,
-                                                     block_size=blk),
-                 extra), argn)(q, k, v, *rest)
+        g_pl = jax.grad(loss(pallas, extra), argn)(q, k, v, *rest)
         for nm, a, b in zip(("dq", "dk", "dv") + extra, g_ref, g_pl):
             err = float(jnp.abs(a - b).max())
             assert err <= TOL, f"{name}/{nm}: {err}"
@@ -229,6 +290,62 @@ def test_trainer_band_gauges(impl):
     assert (snap["winattn.grid_steps"]["value"],
             snap["winattn.live_steps"]["value"]) == band_steps(
                 cfg.window, cfg.attn_block_size, n_q) == (8, 7)
+
+
+def _brute_tile_steps(is_sum, window, blk, sum_stream):
+    """Live (q sub-tile, kv step) pairs that run the SUM stream, and all
+    live pairs, of a (B, S) batch, walked one by one."""
+    b, s = is_sum.shape
+    n_q = -(-s // blk)
+    n_kv = n_kv_blocks(window, blk, n_q)
+    sub = min(128, blk)
+    n_sum = n_all = 0
+    for r in range(b):
+        for i in range(n_q):
+            for lo in range(0, blk, sub):
+                held = is_sum[r, i * blk + lo:i * blk + min(lo + sub, blk)]
+                for ikv in range(n_kv):
+                    if i - (n_kv - 1) + ikv >= 0:
+                        n_all += 1
+                        n_sum += int(sum_stream and held.any())
+    return n_sum, n_all
+
+
+@pytest.mark.parametrize("S,blk,W,rate,sum_stream", [
+    (1536, 512, 977, 0.01, True),       # the benchmark cell's geometry
+    (1536, 512, 977, 0.01, False),      # no NoPE or reset: no SUM stream
+    (700, 512, 300, 0.02, True),        # padded tail
+    (384, 192, 200, 0.02, True),        # sub-tiles of 128 and 64 rows
+    (128, 32, 32, 0.05, True),          # blk < 128
+])
+def test_band_tile_steps_match_brute_force(S, blk, W, rate, sum_stream):
+    is_sum = np.random.default_rng(S).random((5, S)) < rate
+    blk, s_pad = choose_block(S, blk)
+    band = Band(W, blk, s_pad // blk, min(128, blk), sum_stream)
+    assert band.tile_steps(is_sum) == _brute_tile_steps(is_sum, W, blk,
+                                                        sum_stream)
+
+
+@pytest.mark.parametrize("sum_token", [True, False])
+def test_trainer_tile_counters(sum_token):
+    """The trainer counts, per batch, the live (sub-tile, kv step) pairs
+    that run the SUM stream and all live pairs; with DTI off, none run it."""
+    cfg = dataclasses.replace(_gqa_cfg("pallas"), dti_sum_token=sum_token)
+    ocfg = OptimizerConfig(lr=1e-3)
+    step = make_train_step(make_lm_loss_fn(cfg, cfg.window), ocfg)
+    trainer = Trainer(step, init_train_state(
+        init_params(jax.random.PRNGKey(0), cfg), ocfg), log_every=100)
+    batches = [_batch(), _batch(packed=True)]
+    trainer.run(iter([{k: np.asarray(x) for k, x in b.items()}
+                      for b in batches]), n_steps=2)
+    snap = trainer.metrics.snapshot("winattn.")
+    want = np.sum([_brute_tile_steps(np.asarray(b["is_sum"]), cfg.window,
+                                     cfg.attn_block_size, sum_token)
+                   for b in batches], axis=0)
+    got = (snap["winattn.sum_tile_steps"]["value"],
+           snap["winattn.tile_steps"]["value"])
+    assert got == tuple(want)
+    assert (want[0] > 0) == sum_token
 
 
 # ---------------------------------------------------------------------------

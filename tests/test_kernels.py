@@ -10,7 +10,8 @@ from repro.kernels.windowed_attn.ops import windowed_attention
 from repro.kernels.windowed_attn.ref import reference_attention
 from repro.kernels.windowed_attn.windowed_attn import (band_steps,
                                                        choose_block,
-                                                       n_kv_blocks)
+                                                       n_kv_blocks,
+                                                       prepare_inputs)
 from repro.core.windowed import ResetConfig
 from repro.models.layers import alibi_slopes
 
@@ -25,6 +26,8 @@ class TestWindowedAttnKernel:
         (1, 512, 8, 2, 64, 128, 128),
         (3, 192, 6, 3, 16, 64, 64),     # non-pow2 batch/heads
         (1, 96, 2, 2, 8, 61, 32),       # n_kv == n_q == 3, dead steps
+        (2, 512, 2, 1, 16, 200, 256),   # two SUM gate sub-tiles a block
+        (1, 384, 2, 2, 8, 200, 192),    # sub-tiles of 128 and 64 rows
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_sweep(self, B, S, H, Hk, D, W, blk, dtype):
@@ -64,6 +67,43 @@ class TestWindowedAttnKernel:
         dkv = sum(j + ib <= n_q - 1 for j in range(n_q) for ib in range(n_kv))
         assert band_steps(W, blk, n_q) == (n_q * n_kv, fwd) == counts
         assert dkv == fwd
+
+    @pytest.mark.parametrize("S,block_size,rate", [
+        (1536, 512, 0.01),              # the cell's block: 4 sub-tiles
+        (1536, 512, 0.0),               # no SUM: every word 0
+        (700, 512, 0.02),               # padded tail
+        (384, 192, 0.02),               # sub-tiles of 128 and 64 rows
+        (128, 32, 0.05),                # blk < 128: one sub-tile a block
+    ])
+    def test_gate_bits_match_brute_force(self, S, block_size, rate):
+        """Bit t of word (b, i) is set iff sub-tile t of q block i of row b
+        holds a SUM query; no word is built without NoPE and reset."""
+        B, H, D = 3, 2, 8
+        r = np.random.default_rng(S + block_size)
+        is_sum = r.random((B, S)) < rate
+        x = jnp.zeros((B, H, S, D))
+        pos = jnp.zeros((B, S), jnp.int32)
+        common = dict(window=64, sum_q=jnp.asarray(is_sum), sum_k=None,
+                      valid_k=None, seg_q=None, seg_k=None, alibi=None,
+                      sum_isolated=True, scale=None, block_size=block_size,
+                      interpret=True)
+        st, arrays = prepare_inputs(x, x, x, pos, pos, q_nope=x, k_nope=x,
+                                    v0=None, reset=None, **common)
+        blk, n_q = st.block, arrays[0].shape[2] // st.block
+        sub = min(128, blk)
+        want = np.zeros((B, n_q), np.int64)
+        for b in range(B):
+            for i in range(n_q):
+                for t in range(-(-blk // sub)):
+                    lo = i * blk + t * sub
+                    if is_sum[b, lo:min(lo + sub, (i + 1) * blk)].any():
+                        want[b, i] |= 1 << t
+        np.testing.assert_array_equal(np.asarray(arrays[-1]),
+                                      want.reshape(-1))
+        _, arrays = prepare_inputs(x, x, x, pos, pos, q_nope=None,
+                                   k_nope=None, v0=None, reset=None,
+                                   **common)
+        assert arrays[-1] is None
 
     def test_jit_and_grad_through_kernel(self):
         B, S, H, D, W = 1, 128, 2, 16, 32
