@@ -17,7 +17,8 @@ token after each target. ``pack_prompts`` then bin-packs several prompts
 into one row (first-fit decreasing); attention layers isolate the segments
 via ``segment_ids`` so prompts from different users share a row without
 hidden-state leakage. Token budget bookkeeping (`PromptStats`, including
-pad-slot accounting) feeds the Eq. 3 validation benchmark.
+pad-slot accounting) feeds the pad fractions that the trainer CLI and
+the stream pipeline report.
 """
 from __future__ import annotations
 
@@ -309,9 +310,9 @@ def train_max_len(n_ctx: int, k: int, avg_item_tokens: float) -> int:
     """Fixed-shape training row length for prompts with ``n_ctx`` context
     interactions and ``k`` targets (1 for sliding-window): headroom over the
     expected token count (BOS, one [SUM] per target, margin), rounded up to
-    a multiple of 64. The single source of truth shared by the trainer and
-    the benchmarks — pad-fraction numbers are only comparable when every
-    harness builds rows of this shape."""
+    a multiple of 64. The single source of truth for every caller that
+    builds training rows — pad-fraction numbers are only comparable when
+    every row has this shape."""
     n = int((n_ctx + k) * (avg_item_tokens + 1.5) + 8)
     return ((n + 63) // 64) * 64
 
@@ -325,8 +326,8 @@ def window_tokens(n_ctx: int, avg_item_tokens: float, cap: int = 1024) -> int:
 def effective_window(attn_impl: str, window: int, n_ctx: int,
                      avg_item_tokens: float) -> int:
     """Banded attention paths (blocked / pallas) need a finite window;
-    dense treats 0 as unlimited. One rule shared by the trainer CLI and
-    the benchmark harness so they always train with the same window."""
+    dense treats 0 as unlimited. One rule for every caller that trains,
+    so they all train with the same window."""
     if attn_impl != "dense" and window == 0:
         return window_tokens(n_ctx, avg_item_tokens)
     return window

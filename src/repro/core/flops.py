@@ -1,21 +1,10 @@
-"""FLOPs accounting: the paper's Eq. 3 model and an exact per-step model.
+"""FLOPs accounting: the paper's Eq. 3 model (section 3.5).
 
-Two granularities:
-  * ``sliding_window_flops`` / ``dti_flops`` / ``flops_reduction`` — the
-    paper's own approximation (section 3.5), used to validate Eq. 3 and the
-    92% claim.
-  * ``transformer_step_flops`` — exact matmul counting for an arch config,
-    used as MODEL_FLOPS in the roofline analysis (6*N*D for dense LMs,
-    6*N_active*D for MoE, attention terms windowed or full).
+``sliding_window_flops`` / ``dti_flops`` / ``flops_reduction_*`` are the
+paper's own approximation, used to validate Eq. 3 and the 92% claim. The
+chip benchmark's exact per-step accounts live in ``bench/accounts/``.
 """
 from __future__ import annotations
-
-from dataclasses import dataclass
-from typing import Optional
-
-from typing import TYPE_CHECKING
-if TYPE_CHECKING:
-    from repro.models.transformer import ModelConfig
 
 
 # ---------------------------------------------------------------------------
@@ -41,146 +30,5 @@ def flops_reduction_approx(N: int, K: int, k: int) -> float:
     return N * k / (N + K)
 
 
-# ---------------------------------------------------------------------------
-# Exact per-step model FLOPs (roofline MODEL_FLOPS)
-# ---------------------------------------------------------------------------
-
-@dataclass
-class FlopsBreakdown:
-    qkv: float
-    attn_scores: float
-    attn_values: float
-    out_proj: float
-    ffn: float
-    lm_head: float
-    embed: float = 0.0
-
-    @property
-    def total(self) -> float:
-        return (self.qkv + self.attn_scores + self.attn_values
-                + self.out_proj + self.ffn + self.lm_head + self.embed)
-
-
-def _attn_dims(cfg: "ModelConfig"):
-    if cfg.attn_type == "mla":
-        qk = cfg.qk_nope_dim + cfg.qk_rope_dim
-        return qk, cfg.v_head_dim
-    return cfg.hd, cfg.hd
-
-
-def transformer_fwd_flops(cfg: "ModelConfig", batch: int, seq: int, *,
-                          kv_len: Optional[int] = None,
-                          with_lm_head: bool = True,
-                          dti_sum_rows: bool = False) -> FlopsBreakdown:
-    """Forward matmul FLOPs (2*m*n*k per matmul) for one step.
-
-    kv_len: attended context per query (window or full seq). Defaults to
-    full causal (avg seq/2 per query).
-    """
-    t = batch * seq
-    d = cfg.d_model
-    qk_d, v_d = _attn_dims(cfg)
-    h, hk = cfg.n_heads, cfg.n_kv_heads
-
-    if cfg.attn_type == "mla":
-        q_in = (2 * t * d * cfg.q_lora_rank + 2 * t * cfg.q_lora_rank * h * qk_d
-                ) if cfg.q_lora_rank else 2 * t * d * h * qk_d
-        kv_in = (2 * t * d * cfg.kv_lora_rank
-                 + 2 * t * cfg.kv_lora_rank * h * (cfg.qk_nope_dim + v_d)
-                 + 2 * t * d * cfg.qk_rope_dim)
-        qkv = q_in + kv_in
-    else:
-        qkv = 2 * t * d * (h * qk_d + 2 * hk * qk_d)
-
-    ctx = kv_len if kv_len is not None else seq / 2.0
-    scores = 2 * t * h * qk_d * ctx
-    values = 2 * t * h * v_d * ctx
-    if dti_sum_rows:
-        scores *= 2          # dual (RoPE + NoPE/ALiBi) score matrices
-        values *= 2          # reset: second value aggregation
-    out = 2 * t * h * v_d * d
-
-    if cfg.moe:
-        active = cfg.top_k + cfg.n_shared_experts
-        moe_l = cfg.n_layers - cfg.first_dense_layers
-        dense_l = cfg.first_dense_layers
-        sdf = cfg.shared_d_ff or cfg.moe_d_ff
-        ffn = (moe_l * (2 * 3 * t * d * (cfg.top_k * cfg.moe_d_ff
-                                         + cfg.n_shared_experts * sdf))
-               + dense_l * 2 * 3 * t * d * cfg.d_ff
-               + moe_l * 2 * t * d * cfg.n_experts)     # router
-        ffn /= cfg.n_layers  # report per layer, scaled back below
-    else:
-        ffn = 2 * 3 * t * d * cfg.d_ff
-
-    L = cfg.n_layers
-    lm = 2 * t * d * cfg.vocab_size if with_lm_head else 0.0
-    return FlopsBreakdown(qkv=L * qkv, attn_scores=L * scores,
-                          attn_values=L * values, out_proj=L * out,
-                          ffn=L * ffn, lm_head=lm)
-
-
-def train_step_flops(cfg: "ModelConfig", batch: int, seq: int, *,
-                     kv_len: Optional[int] = None,
-                     dti_sum_rows: bool = False) -> float:
-    """fwd + bwd ~= 3x fwd for matmuls (grad wrt inputs and weights)."""
-    return 3 * transformer_fwd_flops(cfg, batch, seq, kv_len=kv_len,
-                                     dti_sum_rows=dti_sum_rows).total
-
-
-def param_count_active(cfg: "ModelConfig") -> float:
-    """Active (per-token) params, for the 6*N*D rule."""
-    d = cfg.d_model
-    qk_d, v_d = _attn_dims(cfg)
-    h, hk = cfg.n_heads, cfg.n_kv_heads
-    if cfg.attn_type == "mla":
-        attn = (d * cfg.q_lora_rank + cfg.q_lora_rank * h * qk_d
-                if cfg.q_lora_rank else d * h * qk_d)
-        attn += (d * cfg.kv_lora_rank
-                 + cfg.kv_lora_rank * h * (cfg.qk_nope_dim + v_d)
-                 + d * cfg.qk_rope_dim)
-    else:
-        attn = d * qk_d * (h + 2 * hk)
-    attn += h * v_d * d
-    if cfg.moe:
-        sdf = cfg.shared_d_ff or cfg.moe_d_ff
-        moe_l = cfg.n_layers - cfg.first_dense_layers
-        ffn_total = (moe_l * 3 * d * (cfg.top_k * cfg.moe_d_ff
-                                      + cfg.n_shared_experts * sdf)
-                     + cfg.first_dense_layers * 3 * d * cfg.d_ff)
-        ffn = ffn_total / cfg.n_layers
-    else:
-        ffn = 3 * d * cfg.d_ff
-    return cfg.n_layers * (attn + ffn) + cfg.vocab_size * d
-
-
-def param_count_total(cfg: "ModelConfig") -> float:
-    d = cfg.d_model
-    qk_d, v_d = _attn_dims(cfg)
-    h, hk = cfg.n_heads, cfg.n_kv_heads
-    if cfg.attn_type == "mla":
-        attn = (d * cfg.q_lora_rank + cfg.q_lora_rank * h * qk_d
-                if cfg.q_lora_rank else d * h * qk_d)
-        attn += (d * cfg.kv_lora_rank
-                 + cfg.kv_lora_rank * h * (cfg.qk_nope_dim + v_d)
-                 + d * cfg.qk_rope_dim)
-    else:
-        attn = d * qk_d * (h + 2 * hk)
-    attn += h * v_d * d
-    if cfg.moe:
-        sdf = cfg.shared_d_ff or cfg.moe_d_ff
-        moe_l = cfg.n_layers - cfg.first_dense_layers
-        ffn_total = (moe_l * (3 * d * cfg.n_experts * cfg.moe_d_ff
-                              + 3 * d * cfg.n_shared_experts * sdf
-                              + d * cfg.n_experts)
-                     + cfg.first_dense_layers * 3 * d * cfg.d_ff)
-    else:
-        ffn_total = cfg.n_layers * 3 * d * cfg.d_ff
-    emb = cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2)
-    return cfg.n_layers * attn + ffn_total + emb
-
-
 __all__ = ["sliding_window_flops", "dti_flops", "flops_reduction_exact",
-           "flops_reduction_approx", "transformer_fwd_flops",
-           "train_step_flops", "param_count_active", "param_count_total",
-           "FlopsBreakdown"]
+           "flops_reduction_approx"]

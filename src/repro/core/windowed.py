@@ -8,7 +8,7 @@ Three execution paths, all semantically identical (tests assert this):
   blocks of the window size W and each query block attends to (previous block,
   own block) only. O(S * 2W) time/memory instead of O(S^2). This is the shape
   the Pallas kernel (`repro.kernels.windowed_attn`) implements on TPU and the
-  shape used by every large dry-run cell.
+  shape used by every large compile cell.
 * ``repro.kernels.windowed_attn.ops.windowed_attention`` — the fused TPU
   kernel (validated against ``attention_dense`` in interpret mode). It is
   differentiable: a custom VJP pairs the forward (which saves per-row

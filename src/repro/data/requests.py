@@ -12,11 +12,11 @@ exercise realistic token-length distributions:
   earlier contexts with fresh slates (the "same user, next page view"
   shape) so schedulers exercising cross-request prefix sharing see hits.
   Consumed by ``repro.serve.scheduler.ServeScheduler.submit``,
-  ``CTRServer.score_multi_target`` and ``benchmarks/serve_bench.py``.
+  ``CTRServer.score_multi_target`` and the serving tests.
 * ``make_event_stream``    — training events: each user's *future*
   interactions replayed in chronological per-user order, interleaved
   across users and sliced into arrival ticks. Consumed by
-  ``repro.stream`` (incremental DTI) and ``benchmarks/stream_bench.py``.
+  ``repro.stream`` (incremental DTI).
 
 ``ContextTrie`` indexes committed context token sequences so admission can
 find, in O(|new context|), the deepest already-cached prefix of an
@@ -65,7 +65,8 @@ def make_request_stream(ds: CTRDataset, *, n_requests: int, k: int,
     Most requests stay near ``n_ctx``; a few are much longer — the
     mixed-length traffic shape whose tail a batched scheduler must not let
     one long prefill impose on every co-batched short slate (the
-    ``--ctx-heavy-tail`` workload of benchmarks/serve_bench.py). Alpha 1.5
+    shape of the chip benchmark's ``ranking-open`` mix,
+    ``bench/traffic/open_loop.py``). Alpha 1.5
     is the classic infinite-variance web-traffic tail.
 
     Draw order per request (fixed so seeded runs are byte-deterministic):

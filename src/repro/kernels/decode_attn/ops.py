@@ -66,11 +66,11 @@ def decode_attention(
     """Fused burst attention into the batched KV cache -> (B, s, H, Dv).
 
     ``block_size=None`` resolves the kv tile via
-    ``repro.kernels.autotune.decode_block`` (measured winner on TPU when
-    one exists, geometry table otherwise; the historic 64 in interpret
-    mode). ``k_scale`` switches to the quantized-KV contract: ``k``/``v``
-    are raw int8 cache codes and dequant + RoPE (span ``[rope_start:]``,
-    base ``rope_theta``) happen inside the kernel — see docs/kernels.md.
+    ``repro.kernels.autotune.decode_block`` (its geometry table on TPU,
+    the historic 64 in interpret mode). ``k_scale`` switches to the
+    quantized-KV contract: ``k``/``v`` are raw int8 cache codes and
+    dequant + RoPE (span ``[rope_start:]``, base ``rope_theta``) happen
+    inside the kernel — see docs/kernels.md.
 
     ``mesh`` runs the kernel once per shard under ``shard_map`` (GSPMD
     cannot partition a Mosaic kernel): cache rows over ``data`` and KV

@@ -1,8 +1,5 @@
-"""repro.launch — meshes, cell builders, dry-run + training entry points.
+"""repro.launch — meshes, cell builders and the training entry point.
 
-NOTE: repro.launch.dryrun must be imported/run as the process entry point
-(it sets XLA_FLAGS for 512 placeholder devices before jax loads); nothing
-here imports it.
+The package imports nothing itself: ``repro.launch.train`` does not load
+the compile grid of ``repro.launch.steps``; import each module directly.
 """
-from repro.launch.mesh import make_cpu_mesh, make_production_mesh
-from repro.launch.steps import Cell, build_cell

@@ -1,8 +1,7 @@
 """JAX's persistent compilation cache, kept at one fixed path.
 
 Entry points that compile full-size programs (``chip_smoke.py``,
-``repro.launch.train``, ``benchmarks.serve_bench``,
-``benchmarks.stream_bench``) call :func:`enable_compile_cache` once, before
+``repro.launch.train``) call :func:`enable_compile_cache` once, before
 their first compile, so a second run of the same programs loads them
 instead of compiling again. The cache path is part of the cache key, so it
 never moves: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself),

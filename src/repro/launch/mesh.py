@@ -1,10 +1,8 @@
-"""Mesh builders for the production pods.
+"""Mesh builders.
 
 Functions, not module-level constants: importing this module never touches
-jax device state. The dry-run entry point (repro.launch.dryrun) sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; everything else (tests, benches) sees the 1 real CPU device and uses
-``make_cpu_mesh``.
+jax device state. Tests on one CPU device use ``make_cpu_mesh``; the
+sharded serving lane uses ``make_serve_mesh``.
 """
 from __future__ import annotations
 
@@ -14,13 +12,6 @@ from jax.sharding import AxisType, Mesh
 
 def _make_mesh(shape, axes) -> Mesh:
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
 
 
 def make_cpu_mesh() -> Mesh:
@@ -47,4 +38,4 @@ def make_serve_mesh(dp: int = 2, mp: int = 4) -> Mesh:
     return _make_mesh((dp, mp), ("data", "model"))
 
 
-__all__ = ["make_production_mesh", "make_cpu_mesh", "make_serve_mesh"]
+__all__ = ["make_cpu_mesh", "make_serve_mesh"]

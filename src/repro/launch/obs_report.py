@@ -2,9 +2,8 @@
 
 Validates the Chrome-trace document against the obs schema first
 (:func:`repro.obs.trace.validate_chrome_trace`) and exits nonzero on a
-malformed or empty trace — CI runs this on the ``serve_bench --trace``
-artifact, so a bench change that breaks trace export fails the job, not
-just the viewer.
+malformed or empty trace, so a trace that the viewer would reject fails
+here first. Traces come from ``SpanTracer.save``.
 
 On a valid trace it prints per-span-name aggregates (count, total /
 mean / p99 / max milliseconds, sorted by total), instant-event counts
@@ -54,7 +53,7 @@ def summarize(doc: Dict) -> Dict:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="validate + summarize a repro Chrome trace")
-    ap.add_argument("trace", help="trace JSON path (serve_bench --trace)")
+    ap.add_argument("trace", help="trace JSON path (SpanTracer.save)")
     ap.add_argument("--json", default=None, metavar="OUT",
                     help="also write the summary as JSON")
     args = ap.parse_args(argv)

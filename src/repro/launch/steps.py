@@ -4,11 +4,11 @@ A *cell* is one graded (architecture x input-shape) combination. For each,
 ``build_cell`` returns the real step function (train step incl. optimizer
 update, prefill, decode, serve or retrieval — whatever the shape's kind
 dictates) plus ShapeDtypeStruct stand-ins for every input with NamedSharding
-attached, so the dry-run can ``jit(...).lower(*args).compile()`` without
+attached, so a caller can ``jit(...).lower(*args).compile()`` without
 allocating anything.
 
 The same builders back the smoke tests (pass ``smoke=True`` + the CPU mesh)
-— the dry-run cells and the tests exercise identical code.
+and the compile sweep of ``tests/test_sharding.py``.
 """
 from __future__ import annotations
 
@@ -23,10 +23,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_arch
 from repro.configs.base import ArchSpec, ShapeSpec
 from repro.core.dti import SpecialTokens
-from repro.core.flops import (param_count_active, param_count_total,
-                              train_step_flops, transformer_fwd_flops)
 from repro.core.losses import ctr_loss
-from repro.models.gnn import GNNConfig, gin_forward, gin_graph_forward, init_gin
+from repro.models.gnn import gin_forward, gin_graph_forward, init_gin
 from repro.models.recsys import (RecsysConfig, _din_attend, bce_loss,
                                  init_recsys, mind_retrieval, recsys_logits,
                                  sasrec_encode)
@@ -51,7 +49,6 @@ class Cell:
     step_fn: Callable
     args: Tuple[Any, ...]
     donate: Tuple[int, ...]
-    meta: Dict[str, Any]
 
 
 # ---------------------------------------------------------------------------
@@ -257,22 +254,12 @@ def _lm_train_cell(spec: ArchSpec, shape: ShapeSpec, mesh, cfg: ModelConfig,
             batch_axis = ("data", "model")
             grad_accum = max(1, min(grad_accum, b // full))
     batch_sds = _lm_batch_specs(mesh, b, s, axis=batch_axis)
-
-    tokens = b * s
-    meta = dict(
-        tokens_per_step=tokens,
-        model_flops=train_step_flops(cfg, b, s, kv_len=win,
-                                     dti_sum_rows=True),
-        six_nd_flops=6.0 * param_count_active(cfg) * tokens,
-        params_total=param_count_total(cfg),
-        grad_accum=grad_accum, remat_policy=cfg.remat_policy,
-    )
     return Cell(spec.name, shape.name, "train",
                 _make_train_step(loss_fn, ocfg, grad_accum=grad_accum,
                                  grad_shardings=mu_specs,
                                  batch_shardings=batch_sds),
                 (_attach(state_shape, state_specs), batch_sds),
-                donate=(0,), meta=meta)
+                donate=(0,))
 
 
 def _lm_prefill_cell(spec: ArchSpec, shape: ShapeSpec, mesh,
@@ -326,16 +313,9 @@ def _lm_prefill_cell(spec: ArchSpec, shape: ShapeSpec, mesh,
     param_specs = make_param_specs(params_shape, rules_for("lm", prof), mesh)
     batch_sds = {k: v for k, v in _lm_batch_specs(mesh, b, s).items()
                  if k != "labels"}
-    meta = dict(
-        tokens_per_step=b * s,
-        model_flops=transformer_fwd_flops(cfg, b, s, kv_len=win,
-                                          with_lm_head=False).total,
-        six_nd_flops=2.0 * param_count_active(cfg) * b * s,
-        params_total=param_count_total(cfg),
-    )
     return Cell(spec.name, shape.name, "prefill", step,
                 (_attach(params_shape, param_specs), batch_sds),
-                donate=(), meta=meta)
+                donate=())
 
 
 def _lm_decode_cell(spec: ArchSpec, shape: ShapeSpec, mesh, cfg: ModelConfig,
@@ -368,21 +348,12 @@ def _lm_decode_cell(spec: ArchSpec, shape: ShapeSpec, mesh, cfg: ModelConfig,
 
     tok_sds = _sds(mesh, (b, 1), jnp.int32, "data", None)
     sum_sds = _sds(mesh, (b, 1), jnp.bool_, "data", None)
-    meta = dict(
-        tokens_per_step=b,
-        model_flops=transformer_fwd_flops(
-            cfg, b, 1, kv_len=min(win, capacity), with_lm_head=False).total,
-        six_nd_flops=2.0 * param_count_active(cfg) * b,
-        params_total=param_count_total(cfg),
-        cache_capacity=capacity, ring=ring,
-        logical_len=p["cache_len"],
-    )
     return Cell(spec.name, shape.name, "decode_ring" if ring else "decode",
                 step,
                 (_attach(params_shape, param_specs),
                  _attach(cache_shape_, cache_specs), tok_sds, tok_sds,
                  sum_sds),
-                donate=(1,), meta=meta)
+                donate=(1,))
 
 
 # ---------------------------------------------------------------------------
@@ -402,39 +373,6 @@ def _recsys_batch_specs(mesh, cfg: RecsysConfig, b, *, labels: bool):
     return out
 
 
-def _recsys_flops(cfg: RecsysConfig, b: int) -> float:
-    """Rough per-example matmul FLOPs (forward)."""
-    d = cfg.embed_dim
-    if cfg.kind == "xdeepfm":
-        m = len(cfg.field_vocabs)
-        cin = 0.0
-        h_prev = m
-        for h in cfg.cin_layers:
-            cin += 2 * h * h_prev * m * d          # compress einsum
-            cin += h_prev * m * d                  # outer product
-            h_prev = h
-        dims = [m * d, *cfg.dnn_dims, 1]
-        dnn = sum(2 * a * bb for a, bb in zip(dims[:-1], dims[1:]))
-        return b * (cin + dnn)
-    if cfg.kind == "din":
-        l = cfg.seq_len
-        attn_dims = [4 * d, *cfg.attn_mlp, 1]
-        attn = l * sum(2 * a * bb for a, bb in zip(attn_dims[:-1],
-                                                   attn_dims[1:]))
-        head_dims = [3 * d, *cfg.head_mlp, 1]
-        head = sum(2 * a * bb for a, bb in zip(head_dims[:-1], head_dims[1:]))
-        return b * (attn + head + 2 * l * d)
-    if cfg.kind == "sasrec":
-        l = cfg.seq_len
-        per_blk = 4 * 2 * l * d * d + 2 * 2 * l * l * d + 2 * 2 * l * d * d
-        return b * (cfg.n_blocks * per_blk + 2 * d)
-    if cfg.kind == "mind":
-        l, k = cfg.seq_len, cfg.n_interests
-        routing = cfg.capsule_iters * 2 * (2 * k * l * d)
-        return b * (2 * l * d * d + routing + 2 * 2 * d * 64)
-    raise ValueError(cfg.kind)
-
-
 def _recsys_train_cell(spec, shape, mesh, cfg: RecsysConfig, overrides) -> Cell:
     b = overrides.get("global_batch", shape.params["batch"])
     ocfg = OptimizerConfig(lr=1e-3, schedule="cosine", total_steps=10_000)
@@ -448,25 +386,10 @@ def _recsys_train_cell(spec, shape, mesh, cfg: RecsysConfig, overrides) -> Cell:
     state_shape, state_specs, mu_specs = _train_state_specs(
         params_shape, ocfg, rules, mesh)
     batch_sds = _recsys_batch_specs(mesh, cfg, b, labels=True)
-    meta = dict(tokens_per_step=b,
-                model_flops=3 * _recsys_flops(cfg, b),
-                embed_rows=_embed_rows(cfg),
-                params_total=_recsys_params(params_shape))
     return Cell(spec.name, shape.name, "train",
                 _make_train_step(loss_fn, ocfg),
                 (_attach(state_shape, state_specs), batch_sds),
-                donate=(0,), meta=meta)
-
-
-def _embed_rows(cfg: RecsysConfig) -> int:
-    if cfg.kind == "xdeepfm":
-        return sum(cfg.field_vocabs)
-    return cfg.n_items
-
-
-def _recsys_params(params_shape) -> int:
-    return sum(int(jnp.prod(jnp.asarray(l.shape))) if l.shape else 1
-               for l in jax.tree_util.tree_leaves(params_shape))
+                donate=(0,))
 
 
 def _recsys_serve_cell(spec, shape, mesh, cfg: RecsysConfig, overrides) -> Cell:
@@ -480,11 +403,9 @@ def _recsys_serve_cell(spec, shape, mesh, cfg: RecsysConfig, overrides) -> Cell:
         lambda: init_recsys(jax.random.PRNGKey(0), cfg))
     param_specs = make_param_specs(params_shape, rules_for("recsys"), mesh)
     batch_sds = _recsys_batch_specs(mesh, cfg, b, labels=False)
-    meta = dict(tokens_per_step=b, model_flops=_recsys_flops(cfg, b),
-                embed_rows=_embed_rows(cfg))
     return Cell(spec.name, shape.name, "serve", step,
                 (_attach(params_shape, param_specs), batch_sds),
-                donate=(), meta=meta)
+                donate=())
 
 
 RETRIEVAL_CHUNK = 8000
@@ -552,11 +473,9 @@ def _recsys_retrieval_cell(spec, shape, mesh, cfg: RecsysConfig,
     else:
         batch_sds["hist"] = _sds(mesh, (1, cfg.seq_len), jnp.int32,
                                  None, None)
-    meta = dict(tokens_per_step=c, model_flops=_recsys_flops(cfg, c),
-                embed_rows=_embed_rows(cfg))
     return Cell(spec.name, shape.name, "retrieval", step,
                 (_attach(params_shape, param_specs), batch_sds),
-                donate=(), meta=meta)
+                donate=())
 
 
 # ---------------------------------------------------------------------------
@@ -571,13 +490,6 @@ def _ce_loss(logits, labels, mask=None):
         return jnp.mean(nll)
     w = mask.astype(jnp.float32)
     return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1.0)
-
-
-def _gnn_flops(cfg: GNNConfig, n_nodes: int, n_edges: int) -> float:
-    d = cfg.d_hidden
-    per_layer = 2 * n_nodes * d * d * 2 + n_edges * d   # MLP + scatter adds
-    return (2 * n_nodes * cfg.d_feat * d + cfg.n_layers * per_layer
-            + 2 * n_nodes * d * cfg.n_classes)
 
 
 def _gnn_cell(spec: ArchSpec, shape: ShapeSpec, mesh, overrides,
@@ -613,7 +525,6 @@ def _gnn_cell(spec: ArchSpec, shape: ShapeSpec, mesh, overrides,
             "labels": _sds(mesh, (n,), jnp.int32, None),
             "label_mask": _sds(mesh, (n,), jnp.bool_, None),
         }
-        meta_tokens = n
     elif kind == "graph_batched":
         bsz, nn, ne = p["batch"], p["n_nodes"], p["n_edges"]
         n, e = bsz * nn, bsz * ne
@@ -633,7 +544,6 @@ def _gnn_cell(spec: ArchSpec, shape: ShapeSpec, mesh, overrides,
             "graph_ids": _sds(mesh, (n,), jnp.int32, None),
             "labels": _sds(mesh, (bsz,), jnp.int32, None),
         }
-        meta_tokens = n
     else:
         raise ValueError(kind)
 
@@ -642,13 +552,10 @@ def _gnn_cell(spec: ArchSpec, shape: ShapeSpec, mesh, overrides,
     rules = rules_for("gnn")
     state_shape, state_specs, _ = _train_state_specs(params_shape, ocfg,
                                                      rules, mesh, zero1=False)
-    n_e = e if kind != "graph_sampled" else e
-    meta = dict(tokens_per_step=meta_tokens,
-                model_flops=3 * _gnn_flops(cfg, meta_tokens, n_e))
     return Cell(spec.name, shape.name, kind,
                 _make_train_step(loss_fn, ocfg),
                 (_attach(state_shape, state_specs), batch_sds),
-                donate=(0,), meta=meta)
+                donate=(0,))
 
 
 # ---------------------------------------------------------------------------
@@ -683,7 +590,6 @@ def build_cell(arch_name: str, shape_name: str, mesh, *,
         tensor_axis = "model" if profile in ("tp", "fsdp_tp") else None
         cell.step_fn = with_activation_mesh(cell.step_fn, mesh, batch_axis,
                                             tensor_axis)
-        cell.meta["act_shard"] = True
     return cell
 
 

@@ -6,7 +6,7 @@ from a token->expert cumsum, experts run as one batched einsum, and results
 scatter back weighted by the gate. This wastes zero FLOPs on non-routed pairs
 (the one-hot formulation costs O(T * E * C * d) in pure dispatch matmuls) and
 under GSPMD the gather lowers to activation all-gathers along the expert axis,
-which the roofline pass accounts as collective bytes.
+which ``repro.launch.hlo.collective_bytes`` counts as collective traffic.
 """
 from __future__ import annotations
 

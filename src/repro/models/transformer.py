@@ -6,8 +6,8 @@ the paper's own DTI-Llama configuration. DTI training features (streaming
 prompts / windowed attention / SUM loss / reset / SUM-ALiBi) are enabled per
 forward call via ``DTIAttnOpts`` so the same weights serve both paradigms.
 
-Scan-over-layers keeps the lowered HLO O(1) in depth, which is what makes the
-512-device dry-run compiles tractable; it also gives remat a natural unit.
+Scan-over-layers keeps the lowered HLO O(1) in depth, so compile time does
+not grow with the layers; it also gives remat a natural unit.
 """
 from __future__ import annotations
 

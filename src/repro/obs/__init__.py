@@ -1,4 +1,4 @@
-"""Unified observability layer: span tracing, mergeable metrics, profiling.
+"""Unified observability layer: span tracing, mergeable metrics, compiles.
 
 Small pieces shared by serve / train / stream:
 
@@ -14,9 +14,8 @@ Small pieces shared by serve / train / stream:
   snapshots merge associatively (the same discipline
   ``StreamingAUC`` / ``StreamingLogLoss`` follow), superseding the
   ad-hoc counter dicts in the scheduler, page pool and stream windows.
-- :mod:`repro.obs.profile` — the ``jax.profiler`` trace hook; a trace
-  that was asked for and cannot start raises. A ``jax_annotate`` tracer
-  puts every span on the profiler's host line.
+  A ``jax_annotate`` tracer puts every span on a ``jax.profiler``
+  trace's host line.
 - :mod:`repro.obs.compiles` — the compile watch: every XLA compile as a
   ``jit.compile`` span and ``jit.compiles`` / ``jit.compile_s`` counters
   of the trainer and the scheduler that watch it.

@@ -12,7 +12,10 @@ Merge rules:
   bit-exactly).
 - **histogram** — fixed, identical bucket bounds; per-bin counts,
   ``total`` and ``count`` add; ``min`` / ``max`` combine by min / max
-  (``None`` when empty is the merge identity).
+  (``None`` when empty is the merge identity). The sum is kept exact as
+  a reduced fraction (``exact_total``: numerator, denominator), so
+  ``total`` — its correctly rounded value, an int while the sum is
+  integral — does not depend on the order of observations or merges.
 - **gauge** — last-writer-wins can't be made order-independent, so a
   gauge carries a monotonically increasing ``seq`` and merge picks the
   larger ``(seq, value)`` pair — max is associative and commutative.
@@ -25,6 +28,7 @@ snapshots never alias each other.
 from __future__ import annotations
 
 from bisect import bisect_right
+from fractions import Fraction
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Snapshot = Dict[str, Dict]
@@ -78,6 +82,12 @@ class Gauge:
         return {"type": "gauge", "value": self.value, "seq": self.seq}
 
 
+def _plain(x: Fraction):
+    """An exact sum as a plain number: an int when integral, else the
+    correctly rounded float."""
+    return x.numerator if x.denominator == 1 else float(x)
+
+
 class Histogram:
     """Fixed-bound histogram with exact total / count / min / max.
 
@@ -86,7 +96,7 @@ class Histogram:
     ``mean`` is exact (from ``total``), not bin-approximated.
     """
 
-    __slots__ = ("name", "bounds", "counts", "total", "count",
+    __slots__ = ("name", "bounds", "counts", "exact_total", "count",
                  "vmin", "vmax")
 
     def __init__(self, name: str, bounds: Sequence[float] = DEFAULT_BOUNDS):
@@ -99,7 +109,9 @@ class Histogram:
 
     def observe(self, v) -> None:
         self.counts[bisect_right(self.bounds, v)] += 1
-        self.total += v
+        # ints add as they are; any other real (numpy scalars too) exactly
+        # through its float value
+        self.exact_total += v if isinstance(v, int) else Fraction(float(v))
         self.count += 1
         if self.vmin is None or v < self.vmin:
             self.vmin = v
@@ -107,12 +119,16 @@ class Histogram:
             self.vmax = v
 
     @property
+    def total(self):
+        return _plain(self.exact_total)
+
+    @property
     def mean(self) -> float:
-        return self.total / self.count if self.count else 0.0
+        return float(self.exact_total / self.count) if self.count else 0.0
 
     def reset(self) -> None:
         self.counts: List[int] = [0] * (len(self.bounds) + 1)
-        self.total = 0
+        self.exact_total = Fraction(0)
         self.count = 0
         self.vmin = None
         self.vmax = None
@@ -120,6 +136,8 @@ class Histogram:
     def snapshot(self) -> Dict:
         return {"type": "histogram", "bounds": list(self.bounds),
                 "counts": list(self.counts), "total": self.total,
+                "exact_total": [self.exact_total.numerator,
+                                self.exact_total.denominator],
                 "count": self.count, "min": self.vmin, "max": self.vmax}
 
 
@@ -189,9 +207,11 @@ def _merge_two(a: Dict, b: Dict, name: str) -> Dict:
             raise ValueError(f"merge {name!r}: histogram bounds differ")
         lo = [v for v in (a["min"], b["min"]) if v is not None]
         hi = [v for v in (a["max"], b["max"]) if v is not None]
+        exact = Fraction(*a["exact_total"]) + Fraction(*b["exact_total"])
         return {"type": "histogram", "bounds": list(a["bounds"]),
                 "counts": [x + y for x, y in zip(a["counts"], b["counts"])],
-                "total": a["total"] + b["total"],
+                "total": _plain(exact),
+                "exact_total": [exact.numerator, exact.denominator],
                 "count": a["count"] + b["count"],
                 "min": min(lo) if lo else None,
                 "max": max(hi) if hi else None}
@@ -208,7 +228,8 @@ def merge_snapshots(*snaps: Snapshot) -> Snapshot:
                 out[name] = _merge_two(cur, m, name)
             elif m["type"] == "histogram":
                 out[name] = dict(m, counts=list(m["counts"]),
-                                 bounds=list(m["bounds"]))
+                                 bounds=list(m["bounds"]),
+                                 exact_total=list(m["exact_total"]))
             else:
                 out[name] = dict(m)
     return {n: out[n] for n in sorted(out)}

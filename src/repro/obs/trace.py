@@ -228,8 +228,8 @@ def validate_chrome_trace(doc, *, require_nonempty: bool = True
     An empty list means the document is a well-formed Chrome trace
     (``traceEvents`` array of X/i/C/M events with numeric timestamps,
     non-negative durations and int pid/tid) that Perfetto will load.
-    CI runs this (via ``repro.launch.obs_report``) on the serve-bench
-    trace artifact and fails the job on any problem.
+    ``repro.launch.obs_report`` refuses a trace with any problem, and
+    the trace tests hold the scheduler's and the stream's exports to it.
     """
     problems: List[str] = []
     if not isinstance(doc, dict):

@@ -184,8 +184,8 @@ def kv_cache_bytes(cache: Cache) -> int:
 
 def kv_token_bytes(cache: Cache) -> float:
     """KV bytes per token slot, summed over layers (codes + scales): the
-    per-token cost a pool budget buys — ``serve_bench`` sizes its
-    equal-byte quantized-vs-bf16 pools with this."""
+    per-token cost a pool budget buys, by which equal-byte int8 and bf16
+    pools are sized (``tests/test_kv_quant.py``)."""
     ref = cache["ckv"] if "ckv" in cache else cache["k"]
     n_slots = ref.shape[1]          # global slot axis (paged) or B... cap
     if not is_paged(cache):
@@ -220,7 +220,7 @@ def physical_slots(cache: Cache):
 def cache_shape(cfg: ModelConfig, batch: int, capacity: int,
                 *, dtype=jnp.bfloat16, kv_dtype: str = None,
                 page_size: int = None, n_pages: int = None) -> Dict[str, tuple]:
-    """Shapes/dtypes without allocation (dry-run input specs)."""
+    """Shapes/dtypes without allocation (ahead-of-time compile specs)."""
     import jax
     return jax.eval_shape(lambda: init_lm_cache(cfg, batch, capacity,
                                                 dtype=dtype,

@@ -18,7 +18,7 @@ execution paths:
   through W_UV after aggregation).
 
 ``lax.scan`` over (stacked layer params, stacked cache layers) keeps the
-lowered HLO O(1) in depth for the 512-device dry-run compiles.
+lowered HLO O(1) in depth, so compile time does not grow with the layers.
 
 Beyond the paper's one-prompt-per-candidate procedure, two shared-context
 paths score a whole candidate slate against one user context (the serving

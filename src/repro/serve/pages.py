@@ -23,7 +23,7 @@ Eviction is not the pool's job: when ``alloc`` comes up short the caller
 (the scheduler) reclaims pages from the radix index via
 ``RadixTree.evict_pages`` — least-recently-used pages held only by the
 index — releases them here, and retries. ``evictions`` counts pages
-reclaimed that way for telemetry/benchmarks.
+reclaimed that way, for telemetry.
 """
 from __future__ import annotations
 
@@ -44,7 +44,7 @@ class PagePool:
     (``capacity_tokens``) and one in bytes (``pool_bytes``). With int8 KV
     the sidecar is part of a page's footprint — a page moves with its
     scales — so the byte accounting stays honest across dtypes, which is
-    what lets benchmarks size quantized and bf16 pools to *equal bytes*
+    what lets quantized and bf16 pools be sized to *equal bytes*
     rather than equal page counts.
     """
 

@@ -50,8 +50,8 @@ stalling every co-batched short slate):
   a time without ever inflating the wave's jit shape, and resumes
   mid-context on the next step. (``monolithic_prefill=True`` restores the
   pre-budget behaviour — largest-bucket chunks that drag every
-  co-scheduled burst into the largest jit shape — as a reference mode for
-  `benchmarks/serve_bench.py`.)
+  co-scheduled burst into the largest jit shape — as the reference
+  ``tests/test_scheduler.py`` compares the budgeted policy against.)
 * **One-step-ahead overlap.** The decode step is dispatched async; its
   scores are *not* synced before the next step is built and dispatched
   from already-decided host state. Harvest (the only
@@ -432,7 +432,7 @@ class ServeScheduler:
             n_pages=n_pages if self.paged else None)
         # per-token KV footprint (codes + scale sidecars, all layers):
         # stamped on the pool so capacity can be asked in bytes — what lets
-        # benchmarks size int8 and bf16 pools to equal HBM budgets
+        # int8 and bf16 pools be sized to equal HBM budgets
         self._kv_token_bytes = kv_token_bytes(self.cache)
         if self.paged:
             self._pool.token_bytes = self._kv_token_bytes
@@ -585,7 +585,7 @@ class ServeScheduler:
                                 if self._c_ctx_done.value else 0.0),
             # KV footprint: dtype, whole-cache bytes, per-token bytes
             # (codes + any scale sidecar) and bytes landed by commits —
-            # the equal-HBM-budget axis of the quantized-vs-bf16 benches
+            # the equal-HBM-budget axis of an int8-vs-bf16 comparison
             "kv_dtype": self.kv_dtype or "native",
             "kv_bytes": int(kv_cache_bytes(self.cache)),
             "kv_token_bytes": float(self._kv_token_bytes),

@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests run on the 1 real CPU
-device (the 512-device setting is exclusively the dry-run entry point)."""
+device; the 8-device lane sets XLA_FLAGS in its own environment."""
 import numpy as np
 import pytest
 

@@ -58,8 +58,8 @@ class TestSyntheticCTR:
 
 
 class TestStreams:
-    """Byte-determinism of seeded request/event streams — stream_bench
-    replays must be reproducible run to run."""
+    """Byte-determinism of seeded request/event streams — replays must be
+    reproducible run to run."""
 
     def _ds(self):
         return make_ctr_dataset(n_users=6, n_items=60, seq_len=20,

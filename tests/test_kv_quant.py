@@ -30,6 +30,7 @@ import pytest
 
 from _hyp import given, settings, st
 
+from repro.configs import get_arch
 from repro.core.quant import Q8_MAX, dequantize_q8, quantize_q8
 from repro.data.requests import make_request_stream
 from repro.data.synthetic import make_ctr_dataset
@@ -37,7 +38,8 @@ from repro.kernels.decode_attn.ops import decode_attention
 from repro.kernels.decode_attn.ref import decode_attention_ref
 from repro.models.layers import apply_rope
 from repro.models.transformer import init_params
-from repro.serve.cache import init_lm_cache, is_quantized, kv_token_bytes
+from repro.serve.cache import (cache_shape, init_lm_cache, is_quantized,
+                               kv_token_bytes)
 from repro.serve.scheduler import ServeScheduler
 
 from test_serve import _cfg
@@ -244,6 +246,47 @@ def test_cross_row_adoption_preserves_scales():
     assert again.scores == base           # bit-equal through adoption
     assert again.shared_prefix_tokens == 16
     assert s.telemetry()["cross_row_tokens"] == 16
+
+
+def test_int8_retains_more_cross_row_prefix_at_equal_pool_bytes():
+    """The payoff of int8 KV: on the revisit-heavy mix (24 requests on 2
+    slots, repeat fraction 0.3, the smoke model) a pool of int8 pages
+    sized to a 16-page bf16 pool's bytes holds ~1.8x the pages, so it
+    keeps >= 1.5x the cross-row prefix tokens and a higher prefix hit
+    rate than the bf16 pool it replaces."""
+    cfg = get_arch("dti-llama").smoke
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    ds = make_ctr_dataset(n_users=16, n_items=120, seq_len=12,
+                          vocab_size=cfg.vocab_size, seed=0)
+    reqs = make_request_stream(ds, n_requests=24, k=8, n_ctx=8, seed=0,
+                               repeat_frac=0.3)
+    capacity = max(1 + sum(len(t) for t in r["context"]) for r in reqs) + 64
+    page_size = 16
+    base_pages = max(16, -(-capacity // page_size) + 2)
+    cap_eff = -(-capacity // page_size) * page_size
+
+    def token_bytes(kv_dtype):
+        return kv_token_bytes(cache_shape(
+            cfg, 2, cap_eff, dtype=jnp.bfloat16, kv_dtype=kv_dtype,
+            page_size=page_size, n_pages=base_pages))
+
+    # the floor keeps the int8 pool at or under the bf16 pool's bytes
+    int8_pages = int(base_pages * token_bytes(None) / token_bytes("int8"))
+    tel = {}
+    for kv_dtype, n_pages in ((None, base_pages), ("int8", int8_pages)):
+        s = ServeScheduler(params, cfg, n_slots=2, capacity=capacity,
+                           buckets=(16, 32, 64), cache_dtype=jnp.bfloat16,
+                           kv_dtype=kv_dtype, page_size=page_size,
+                           n_pages=n_pages)
+        rids = [s.submit(r["context"], r["candidates"]) for r in reqs]
+        out = s.run()
+        assert all(np.isfinite(out[r].scores).all() for r in rids)
+        tel[kv_dtype] = s.telemetry()
+        assert not tel[kv_dtype]["watchdog_fired"]
+    bf16, int8 = tel[None], tel["int8"]
+    assert bf16["cross_row_tokens"] > 0
+    assert int8["cross_row_tokens"] >= 1.5 * bf16["cross_row_tokens"]
+    assert int8["prefix_hit_rate"] > bf16["prefix_hit_rate"]
 
 
 @pytest.mark.parametrize("layout", ["contiguous", "paged", "pressure"])

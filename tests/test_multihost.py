@@ -72,7 +72,9 @@ class TestShardedEqualsUnsharded:
         got, sched = _drain(params, cfg, reqs,
                             mesh=make_serve_mesh(2, 4), **kw)
         np.testing.assert_allclose(got, want, atol=1e-4)
-        assert sched.telemetry()["mesh"] == {"data": 2, "model": 4}
+        tel = sched.telemetry()
+        assert tel["mesh"] == {"data": 2, "model": 4}
+        assert not tel["watchdog_fired"]
 
     def test_pool_pressure_on_sharded_slot_axis(self):
         """Eviction/adoption churn on the *sharded* global page pool: the

@@ -333,10 +333,9 @@ def test_telemetry_snapshot_does_not_alias_scheduler_state():
 # ---------------------------------------------------------------------------
 
 def test_scheduler_drain_traces_nested_spans_and_events():
-    """Acceptance mirror of ``serve_bench --trace``: a drain must emit
-    scheduler-step spans nesting the per-unit prefill-chunk/burst spans,
-    plus admission and hot-swap instants, and the document must pass the
-    schema gate CI runs."""
+    """A traced drain must emit scheduler-step spans nesting the per-unit
+    prefill-chunk/burst spans, plus admission and hot-swap instants, and
+    the document must pass the schema gate ``obs_report`` applies."""
     tracer = SpanTracer()
     sched, params, rids = _drained_sched(tracer=tracer)
     sched.step()                             # some pre-swap progress

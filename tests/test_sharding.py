@@ -96,8 +96,7 @@ class TestHLOParser:
 
 class TestCellCompilation:
     """Every graded (arch x shape) cell must lower AND compile with its real
-    sharded step fn — at smoke scale on the CPU mesh here; the production
-    512-device pass is `python -m repro.launch.dryrun` (EXPERIMENTS.md)."""
+    sharded step fn — at smoke scale on the CPU mesh."""
 
     # ~40 XLA lower+compile invocations: excluded from the quick tier-1
     # loop (-m "not slow"); the tier1-multidevice lane runs it in full

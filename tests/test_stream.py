@@ -17,6 +17,8 @@ from repro.core.metrics import StreamingAUC, StreamingLogLoss, auc, log_loss
 from repro.data.requests import make_event_stream, warm_histories
 from repro.data.synthetic import make_ctr_dataset
 from repro.models.transformer import ModelConfig, init_params
+from repro.obs.clock import monotonic
+from repro.obs.trace import SpanTracer, validate_chrome_trace
 from repro.stream import (IncrementalDTI, OnlineTrainer, ParamPublisher,
                           ParamSubscriber, StreamPipeline,
                           make_stream_loss_fn)
@@ -389,26 +391,43 @@ class TestPublishHotSwap:
         assert server.params is p_new
 
 
-def test_stream_bench_machinery_token_reduction(tmp_path):
-    """The bench's replay harness at toy scale: streaming DTI reaches
-    freshness (every new target trained exactly once) with a large
-    supervised-token reduction vs periodic full retrain. The committed
-    BENCH_stream.json (CI `stream-bench` job) carries the >=5x smoke
-    numbers; this guards the machinery."""
-    from benchmarks.stream_bench import main
-    res = main(["--users", "6", "--seq", "24", "--ticks", "6",
-                "--k", "3", "--n-ctx", "4", "--warm-epochs", "1",
-                "--json", str(tmp_path / "BENCH_stream.json")])
-    assert (tmp_path / "BENCH_stream.json").exists()
-    modes = res["modes"]
-    assert set(modes) == {"full_sw", "full_dti", "stream_dti"}
-    red = res["token_reduction_vs_full_retrain"]
-    assert red["full_sw"] >= 5.0
-    assert red["full_dti"] >= 2.0
-    for m in modes.values():
-        assert m["trained_tokens"] > 0 and m["steps"] > 0
-        assert m["auc_over_time"]
-    assert modes["stream_dti"]["freshness_p95_s"] > 0.0
+def test_stream_run_trace_has_tick_and_step_spans():
+    """A streaming run under a ``SpanTracer``, tick by tick as a live
+    stream feeds it: the exported trace validates and holds one
+    ``stream.tick`` span per tick and one ``online.step`` span per train
+    step; every tick's targets are trained after it arrives (freshness > 0)
+    and the online AUC is read after every tick."""
+    tracer = SpanTracer()
+    ds = make_ctr_dataset(n_users=4, n_items=50, seq_len=16,
+                          vocab_size=256, seed=0)
+    inc = IncrementalDTI(n_ctx=N_CTX, k=K, max_len=MAX_LEN)
+    for u, (toks, labels) in enumerate(warm_histories(ds, start_frac=0.5)):
+        inc.seed_history(u, toks, labels, supervised=True)
+    ticks = make_event_stream(ds, n_ticks=3, start_frac=0.5, seed=0)
+    cfg = _cfg()
+    ot = OnlineTrainer(make_stream_loss_fn(cfg, window=0),
+                       init_params(jax.random.PRNGKey(0), cfg),
+                       OptimizerConfig(lr=1e-3, schedule="const",
+                                       warmup_steps=1, total_steps=1000),
+                       publish_every=0, window_targets=8, tracer=tracer)
+    freshness, auc_over_time = [], []
+    for t, tick in enumerate(ticks):
+        t0 = monotonic()
+        pipe = StreamPipeline(iter([tick]), inc, batch_size=2,
+                              tracer=tracer)
+        ot.run(pipe.batches(), rng=jax.random.PRNGKey(t))
+        jax.block_until_ready(ot.state.params)
+        freshness.append(monotonic() - t0)
+        auc_over_time.append(ot.lifetime_auc.value())
+
+    doc = tracer.to_chrome_trace()
+    assert validate_chrome_trace(doc) == []
+    spans = [e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"]
+    assert spans.count("stream.tick") == len(ticks)
+    assert spans.count("online.step") == ot.step > 0
+    assert np.percentile(freshness, 95) > 0.0
+    assert len(auc_over_time) == len(ticks)
+    assert all(0.0 <= a <= 1.0 for a in auc_over_time)
 
 
 class TestPrefixPrewarmer:

@@ -32,6 +32,25 @@ H_MLA, R_KV, D_ROPE = 40, 256, 32
 BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
 
 
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["tpu", "interpret"])
+@pytest.mark.parametrize("kind, size, dim, on_tpu", [
+    ("train", 1536, 128, 512),           # the benchmark cell's rows
+    ("train", 256, 128, 256),
+    ("decode", 2400, 128, 512),
+    ("decode", 256, 64, 256),
+])
+def test_autotune_table_pins(kind, size, dim, on_tpu, interpret):
+    """The block table's values: a TPU block is a deliberate change of this
+    table; interpret mode keeps the historic 256 (train) and 64 (decode)."""
+    if kind == "train":
+        got, interpreted = train_block(size, dim, interpret=interpret), 256
+    else:
+        got, interpreted = decode_block(size, dqk=dim, dv=dim,
+                                        interpret=interpret), 64
+    assert got == (interpreted if interpret else on_tpu)
+
+
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
